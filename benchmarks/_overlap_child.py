@@ -44,7 +44,7 @@ def analyze_batched(op, B_grid, mesh):
 def main():
     op, b, _ = M.convection_diffusion(16, peclet=1.0)
     b_grid = b.reshape(16, 16, 16)
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((8,), ("rows",))
     m = 4
     keys = jax.random.split(jax.random.PRNGKey(0), m)

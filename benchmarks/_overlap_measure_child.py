@@ -20,7 +20,7 @@ jax.config.update("jax_enable_x64", True)
 import repro  # noqa: E402
 from repro.core import SolverConfig  # noqa: E402
 from repro.core import matrices as M  # noqa: E402
-from repro.core.compat import make_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main():

@@ -30,7 +30,7 @@ def main():
     nd = int(n_dev)
     op, b, _ = M.convection_diffusion(32, peclet=1.0)   # 32^3 = 32768 rows
     b_grid = b.reshape(32, 32, 32)
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((nd,), ("rows",))
     out = {"devices": nd}
     for name, solver in (("ssbicgsafe2", ssbicgsafe2_solve),

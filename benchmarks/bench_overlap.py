@@ -30,34 +30,26 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 
-from .common import fmt_table, runtime_dir, write_json
+from repro.launch.peaks import chip_peaks
 
-# v5e-ish constants
-PEAK_FLOPS = 197e12 * 0.05      # fp64-ish effective vector rate on VPU
-HBM_BW = 819e9
-LINK_BW = 50e9
+from .common import fmt_table, run_cpu_child, runtime_dir, write_json
+
+HERE = os.path.dirname(__file__)
+
+_CHIP = chip_peaks()
+PEAK_FLOPS = _CHIP.bf16_flops * 0.05   # fp64-ish effective vector rate on VPU
+HBM_BW = _CHIP.hbm_bytes_per_s
+LINK_BW = _CHIP.ici_link_bytes_per_s
 HOP_LAT = 1e-6                  # per-hop ICI latency
 REDUCE_WORDS = 9 * 8            # 9 fp64 scalars
 
 
 def hlo_proof() -> dict:
-    env = dict(os.environ)
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                       os.pardir, "src"))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(__file__),
-                                      "_overlap_child.py")],
-        capture_output=True, text=True, env=env, timeout=900)
-    if proc.returncode != 0:
-        return {"error": proc.stderr[-2000:]}
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return json.loads(run_cpu_child(
+        [os.path.join(HERE, "_overlap_child.py")], timeout=900))
 
 
 def _torus_dims(P: int):
@@ -130,7 +122,7 @@ def _report_summary(rep) -> dict:
 
 def measured_overlap(quick: bool = False) -> dict:
     """Part 3: capture + analyze real timelines for every binding."""
-    from jax.experimental import enable_x64
+    import jax
 
     import repro
     from repro.core import SolverConfig
@@ -141,7 +133,7 @@ def measured_overlap(quick: bool = False) -> dict:
     nx = 6 if quick else 8
     out: dict = {}
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         op, b, _ = M.poisson3d(nx)
         for sub in ("jnp", "pallas"):
             solver = repro.make_solver(
@@ -169,26 +161,13 @@ def measured_overlap(quick: bool = False) -> dict:
         out["engine"] = _report_summary(eng.last_profile)
 
     # mesh leg: subprocess (needs fake-device XLA_FLAGS before jax init)
-    env = dict(os.environ)
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                       os.pardir, "src"))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable,
-         os.path.join(os.path.dirname(__file__),
-                      "_overlap_measure_child.py"),
-         str(base / "mesh")],
-        capture_output=True, text=True, env=env, timeout=900)
-    if proc.returncode != 0:
-        out["mesh"] = {"error": proc.stderr[-2000:]}
-    else:
-        from repro.observe.profile import ProfileReport
-        rep = ProfileReport.from_json(
-            {k: v for k, v in
-             json.loads(proc.stdout.strip().splitlines()[-1]).items()
-             if k != "converged"})
-        out["mesh"] = _report_summary(rep)
+    from repro.observe.profile import ProfileReport
+    line = run_cpu_child(
+        [os.path.join(HERE, "_overlap_measure_child.py"), base / "mesh"],
+        timeout=900)
+    rep = ProfileReport.from_json(
+        {k: v for k, v in json.loads(line).items() if k != "converged"})
+    out["mesh"] = _report_summary(rep)
     return out
 
 
@@ -198,20 +177,17 @@ def run(quick: bool = False):
     print("HLO dependency structure (8-device lowering):")
     print(json.dumps(proof, indent=2))
 
-    ok = ("error" not in proof
-          and proof["p-bicgsafe"]["independent_of_reduction"] > 0
+    ok = (proof["p-bicgsafe"]["independent_of_reduction"] > 0
           and proof["ssbicgsafe2"]["reduction_needs_permutes"] > 0)
     print(f"comm-hiding structurally possible for p-BiCGSafe and "
           f"impossible for ssBiCGSafe2: {ok}")
     batched = proof.get("p-bicgsafe-batched", {})
-    ok_batched = ("error" not in proof
-                  and batched.get("independent_of_reduction", 0) > 0
+    ok_batched = (batched.get("independent_of_reduction", 0) > 0
                   and batched.get("reduction_needs_permutes", 1) == 0)
     print(f"overlap survives batching+sharding (the (9, m) block "
           f"all-reduce has no edge to the block matvec): {ok_batched}")
     prec = proof.get("p-bicgsafe-block-jacobi", {})
-    ok_prec = ("error" not in proof
-               and prec.get("independent_of_reduction", 0) > 0
+    ok_prec = (prec.get("independent_of_reduction", 0) > 0
                and prec.get("reduction_needs_permutes", 1) == 0)
     print(f"overlap survives preconditioning (block-Jacobi apply inside "
           f"the window, no edge from the reduction): {ok_prec}")
@@ -224,9 +200,6 @@ def run(quick: bool = False):
     measured = measured_overlap(quick)
     mrows = []
     for leg, m in measured.items():
-        if "error" in m:
-            mrows.append([leg, "ERR", "", "", ""])
-            continue
         eff = m["overlap_efficiency"]
         mrows.append([
             leg,

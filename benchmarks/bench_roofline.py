@@ -19,11 +19,14 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-from .common import fmt_table, write_json
+from repro.launch.peaks import chip_peaks
 
-PEAK = 197e12          # bf16 FLOP/s per chip
-HBM = 819e9            # B/s per chip
-LINK = 50e9            # B/s per ICI link
+from .common import fmt_table, run_cpu_child, write_json
+
+_CHIP = chip_peaks()
+PEAK = _CHIP.bf16_flops            # bf16 FLOP/s per chip
+HBM = _CHIP.hbm_bytes_per_s        # B/s per chip
+LINK = _CHIP.ici_link_bytes_per_s  # B/s per ICI link
 
 # parameter counts (total, active) in billions — from the configs
 PARAMS_B = {
@@ -103,20 +106,15 @@ def _ensure_quick_artifacts(out: Path, mesh: str) -> None:
     """Compile the small-grid solver cells in a subprocess (the dry-run
     module forces 512 fake host devices via XLA_FLAGS at import — it
     must not pollute this process)."""
-    import subprocess
-    import sys
-
     nx, ny, nz = QUICK_GRID
     for solver in QUICK_SOLVERS:
         cell = out / mesh / f"solver-{solver}__poisson{nx}x{ny}x{nz}.json"
         if cell.exists():
             continue
-        subprocess.run(
-            [sys.executable, "-m", "repro.launch.dryrun_solver",
-             "--solver", solver, "--nx", str(nx), "--ny", str(ny),
-             "--nz", str(nz), "--maxiter", "50", "--out", str(out),
-             "--force"],
-            check=True, timeout=600)
+        run_cpu_child(
+            ["-m", "repro.launch.dryrun_solver", "--solver", solver,
+             "--nx", nx, "--ny", ny, "--nz", nz, "--maxiter", 50,
+             "--out", out, "--force"], timeout=600)
 
 
 def overlap_claims(recs: dict) -> dict:

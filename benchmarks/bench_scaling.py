@@ -12,31 +12,16 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 
-from .common import fmt_table, write_json
+from .common import fmt_table, run_cpu_child, write_json
 
 
 def run(quick: bool = False):
-    env = dict(os.environ)
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                       os.pardir, "src"))
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("XLA_FLAGS", None)
-
     counts = [1, 4] if quick else [1, 2, 4, 8]
     rows, recs = [], {}
+    child = os.path.join(os.path.dirname(__file__), "_scaling_child.py")
     for nd in counts:
-        proc = subprocess.run(
-            [sys.executable, os.path.join(os.path.dirname(__file__),
-                                          "_scaling_child.py"), str(nd)],
-            capture_output=True, text=True, env=env, timeout=1800)
-        if proc.returncode != 0:
-            rows.append([nd, "ERR", "", ""])
-            recs[nd] = {"error": proc.stderr[-1000:]}
-            continue
-        rec = json.loads(proc.stdout.strip().splitlines()[-1])
+        rec = json.loads(run_cpu_child([child, nd], timeout=1800))
         recs[nd] = rec
         ratio = rec["p-bicgsafe"]["per_iter_us"] / \
             rec["ssbicgsafe2"]["per_iter_us"]

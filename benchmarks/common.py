@@ -2,11 +2,15 @@
 from __future__ import annotations
 
 import json
+import os
+import subprocess
+import sys
 import time
 from datetime import datetime, timezone
 from pathlib import Path
 
 OUT = Path("experiments")
+SRC = Path(__file__).resolve().parent.parent / "src"
 
 #: un-committed runtime output (profiler captures, quick dry-runs,
 #: trajectory reports) — ``.gitignore``'s ``experiments/*`` rule keeps
@@ -21,6 +25,28 @@ def runtime_dir(*parts: str) -> Path:
     p = RUNTIME_OUT.joinpath(*parts)
     p.mkdir(parents=True, exist_ok=True)
     return p
+
+
+def run_cpu_child(args, *, timeout: float) -> str:
+    """Run ``python <args>`` as a fake-CPU-device child and return the
+    last line of its stdout; a failed child raises.
+
+    The child gets ``JAX_PLATFORMS=cpu`` (it stages its own host device
+    count through XLA_FLAGS), so it never reaches for an accelerator
+    that this process may already hold.
+    """
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(SRC) + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, *map(str, args)],
+                          capture_output=True, text=True, env=env,
+                          timeout=timeout)
+    if proc.returncode != 0:
+        raise RuntimeError(f"child {list(args)} exited {proc.returncode}:"
+                           f"\n{proc.stderr[-2000:]}")
+    lines = proc.stdout.strip().splitlines()
+    return lines[-1] if lines else ""
 
 
 def write_json(name: str, obj):

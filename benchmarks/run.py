@@ -42,6 +42,7 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.observe.trajectory import BenchSpec, Metric
 
 REGISTRY = (
@@ -178,6 +179,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of bench names")
     args = ap.parse_args()
+    enable_compile_cache()
 
     specs = list(REGISTRY)
     if args.only:

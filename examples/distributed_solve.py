@@ -33,7 +33,7 @@ def main():
     op, b, xt = M.convection_diffusion(n, peclet=1.0)
     print(f"convection-diffusion, {n}^3 = {n**3:,} unknowns, "
           f"{jax.device_count()} devices, mesh (4, 2) = (data, model)")
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((4, 2), ("data", "model"))
     b_grid = b.reshape(n, n, n)
     for name in ("p-bicgsafe", "ssbicgsafe2", "bicgstab", "p-bicgstab"):
@@ -53,4 +53,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

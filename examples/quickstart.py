@@ -139,6 +139,8 @@ def lm_demo():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     solver_demo()
     precond_demo()
     multirhs_demo()

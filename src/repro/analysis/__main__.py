@@ -40,26 +40,23 @@ def main(argv=None):
                          "contract row)")
     args = ap.parse_args(argv)
 
-    # The mesh smoke needs the fake devices staged before the XLA
-    # backend initializes — but ``python -m repro.analysis`` imports the
-    # repro package (and with it jax) before this file runs.  Stage the
-    # flag and re-exec once if the backend already pinned the device
-    # count.
+    # The mesh smoke needs the fake CPU devices staged before any XLA
+    # backend initializes.  Decide from the environment alone — never by
+    # asking the backend for its devices, which would take an
+    # accelerator this process does not need — and re-exec once on the
+    # CPU with the flag in place.
     if args.devices > 1 and not args.no_mesh:
+        flag = f"--xla_force_host_platform_device_count={args.devices}"
         flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count"
-                f"={args.devices}").strip()
+        if flag not in flags.split():
+            env = dict(os.environ, JAX_PLATFORMS="cpu",
+                       XLA_FLAGS=f"{flags} {flag}".strip())
+            os.execve(sys.executable,
+                      [sys.executable, "-m", "repro.analysis"]
+                      + list(argv if argv is not None else sys.argv[1:]),
+                      env)
     import jax
     jax.config.update("jax_enable_x64", True)
-    if args.devices > 1 and not args.no_mesh \
-            and len(jax.devices()) < args.devices \
-            and os.environ.get("_REPRO_AUDIT_REEXEC") != "1":
-        os.environ["_REPRO_AUDIT_REEXEC"] = "1"
-        os.execv(sys.executable,
-                 [sys.executable, "-m", "repro.analysis"]
-                 + list(argv if argv is not None else sys.argv[1:]))
 
     from repro.analysis.audit import audit_table, run_audit
     from repro.scenarios import ScenarioError

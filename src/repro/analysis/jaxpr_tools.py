@@ -5,9 +5,7 @@ Before :mod:`repro.analysis` existed, ``subjaxprs`` / ``find_while_body``
 ``tests/_distributed_check.py`` and ``benchmarks/_overlap_child.py``,
 and four test files re-derived the reverse transitive-dependency walk
 inline.  Every walker lives here now; the jaxpr vocabulary types come
-from :mod:`repro.core.compat` (``jax.extend.core`` with a
-version-guarded fallback), so none of this emits DeprecationWarnings on
-newer jax.
+from ``jax.extend.core``.
 
 All walkers recurse through nested jaxprs (``pjit``, ``scan``,
 ``while``, custom-call bodies) — a probe must see through the session
@@ -17,7 +15,7 @@ from __future__ import annotations
 
 from typing import Iterable, Iterator, List, Optional, Set, Tuple
 
-from repro.core.compat import Jaxpr, Literal
+from jax.extend.core import Jaxpr, Literal
 
 
 def subjaxprs(eqn) -> Iterator[Jaxpr]:

@@ -61,7 +61,8 @@ from repro.core import SOLVERS
 from repro.core._deprecation import internal_use
 from repro.core.linear_operator import Stencil7Operator
 from repro.core.multirhs import (init_state, result_from_state,
-                                 splice_columns, step_chunk)
+                                 splice_columns, step_chunk,
+                                 verify_columns)
 from repro.core.substrate import SUBSTRATES, SubstrateLike, get_substrate
 from repro.core.types import (DotReduce, SolveResult, SolverConfig,
                               identity_reduce, per_column)
@@ -232,6 +233,8 @@ class LinearSolver:
         if getattr(self.sub, "name", None) == "pallas":
             assert self.kernel_backed, (
                 "substrate resolved to 'pallas' but is not kernel-backed")
+        if not blocked:
+            self.sub.check_dtype(getattr(operator, "dtype", None))
         self.blocked = bool(blocked)
         self.precond_spec = precond
         self.fingerprint = fingerprint
@@ -598,22 +601,30 @@ class LinearSolver:
         return self._run_program(key, build, B, X0, tol_col, mit_col,
                                  r0_star)
 
+    def _build_step_chunk(self) -> Callable:
+        def step_chunk_program(state, k):
+            self._mark_trace()
+            with internal_use():
+                return step_chunk(self.block_matvec, state, k,
+                                  config=self.config,
+                                  dot_reduce=self._dot_reduce,
+                                  substrate=self.sub)
+        return jax.jit(step_chunk_program, static_argnames=("k",))
+
+    def compile_step_chunk(self, state: dict, k: int) -> None:
+        """Compile the :meth:`step_chunk` program for this state's
+        structure without running it (the later call reuses it), so a
+        backend's refusal surfaces apart from faults of a run."""
+        self._require_pbicgsafe("step_chunk")
+        self._program(("step_chunk",), self._build_step_chunk).lower(
+            state, k=int(k)).compile()
+
     def step_chunk(self, state: dict, k: int) -> dict:
         """Advance every live column by up to ``k`` iterations — ONE
         compiled program per k, one (9, m) reduction per iteration."""
         self._require_pbicgsafe("step_chunk")
-
-        def build():
-            def step_chunk_program(state, k):
-                self._mark_trace()
-                with internal_use():
-                    return step_chunk(self.block_matvec, state, k,
-                                      config=self.config,
-                                      dot_reduce=self._dot_reduce,
-                                      substrate=self.sub)
-            return jax.jit(step_chunk_program, static_argnames=("k",))
-
-        return self._run_program(("step_chunk",), build, state, k=int(k))
+        return self._run_program(("step_chunk",), self._build_step_chunk,
+                                 state, k=int(k))
 
     def splice(self, state: dict, refill, B_new, *, tol=None,
                maxiter=None, r0_star=None) -> dict:
@@ -672,6 +683,26 @@ class LinearSolver:
         return self._run_program(
             ("splice_step",), build, state, jnp.asarray(refill), B_new,
             tol_col, mit_col, k=int(k))
+
+    def verify(self, state: dict, B, mask) -> Tuple[dict, jax.Array]:
+        """True-residual check of the masked (converged) columns before
+        they are retired: returns ``(state, true_relres)``, with every
+        masked column whose true residual misses its ``tol``
+        un-converged and residual-replaced (see
+        :func:`repro.core.multirhs.verify_columns`; ``B`` is the raw
+        right-hand-side block the state was built from)."""
+        self._require_pbicgsafe("verify")
+
+        def build():
+            def verify_program(state, B, mask):
+                self._mark_trace()
+                with internal_use():
+                    return verify_columns(self.block_matvec, state,
+                                          self._prep(B), mask)
+            return jax.jit(verify_program)
+
+        return self._run_program(("verify",), build, state,
+                                 self._as_block(B), jnp.asarray(mask))
 
     def result(self, state: dict) -> SolveResult:
         """Package an open-loop state pytree as a :class:`SolveResult`.

@@ -91,6 +91,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .types import SolverConfig
 
@@ -113,7 +114,8 @@ def local_dots(pairs: Sequence[Tuple[jax.Array, jax.Array]],
         elif dtype is not None:
             acc = jnp.sum(a * b, dtype=dtype)
         else:
-            acc = jnp.vdot(a, b)
+            # HIGHEST: a TPU's default f32 dot rounds operands to bf16
+            acc = jnp.vdot(a, b, precision=lax.Precision.HIGHEST)
         outs.append(acc)
     return jnp.stack(outs)
 

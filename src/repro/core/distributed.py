@@ -34,7 +34,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..precond.base import PrecondLike, resolve_precond
-from . import _deprecation, compat
+from . import _deprecation
 from .linear_operator import Stencil7Operator
 from .types import SolveResult, SolverConfig
 
@@ -187,8 +187,8 @@ def build_stencil_solver(solver: Callable,
         breakdown=P(), residual_history=P(), status=P(),
         trace={"buffer": P(), "steps": P()} if config.trace_cap else None)
 
-    fn = compat.shard_map(shard_fn, mesh=mesh, in_specs=(in_specs,),
-                          out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     if jit:
         fn = jax.jit(fn)
     return fn
@@ -287,8 +287,8 @@ def build_stencil_solver_batched(op: Stencil7Operator,
         breakdown=P(), residual_history=P(), status=P(),
         trace={"buffer": P(), "steps": P()} if config.trace_cap else None)
 
-    sharded = compat.shard_map(shard_fn, mesh=mesh, in_specs=(in_specs,),
-                               out_specs=out_specs, check_vma=False)
+    sharded = jax.shard_map(shard_fn, mesh=mesh, in_specs=(in_specs,),
+                            out_specs=out_specs, check_vma=False)
 
     def fn(B_grid):
         if B_grid.ndim != 4:

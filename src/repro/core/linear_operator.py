@@ -25,6 +25,7 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .types import MatVec
 
@@ -44,11 +45,12 @@ class DenseOperator:
     def dtype(self):
         return self.a.dtype
 
+    # HIGHEST: a TPU's default f32 dot rounds operands to bf16
     def matvec(self, x: jax.Array) -> jax.Array:
-        return self.a @ x
+        return jnp.matmul(self.a, x, precision=lax.Precision.HIGHEST)
 
     def rmatvec(self, x: jax.Array) -> jax.Array:
-        return self.a.T @ x
+        return jnp.matmul(self.a.T, x, precision=lax.Precision.HIGHEST)
 
     def diagonal(self) -> jax.Array:
         return jnp.diagonal(self.a)
@@ -243,7 +245,7 @@ def as_matvec(op) -> MatVec:
     if hasattr(op, "matvec"):
         return op.matvec
     a = jnp.asarray(op)
-    return lambda x: a @ x
+    return lambda x: jnp.matmul(a, x, precision=lax.Precision.HIGHEST)
 
 
 # -- deprecation re-exports ---------------------------------------------------

@@ -43,7 +43,10 @@ across heterogeneous requests:
                             per-column Krylov state, mid-flight.  Columns
                             are independent in "individual" blocked mode,
                             so splicing is exact — the surviving columns'
-                            trajectories are untouched.
+                            trajectories are untouched,
+* :func:`verify_columns`  — before a converged column is retired, check
+                            its true residual; a column the recurrence
+                            fooled is residual-replaced and resumes.
 
 State is per-column throughout: ``tol`` and ``maxiter`` are ``(m,)``
 vectors (scalars broadcast via :func:`repro.core.types.per_column`) and
@@ -300,6 +303,68 @@ def splice_columns(bmv: Callable,
         for k in GUARD_FIELDS:
             out[k] = sca(fresh[k], state[k])
     return out
+
+
+def replace_residuals(bmv: Callable, state: dict, mask: jax.Array,
+                      R: jax.Array) -> dict:
+    """Residual replacement (p-BiCGSafe-rr's Alg. 4.1 reset) of the masked
+    columns, given their true residuals ``R = B - A X``.
+
+    The recurred residual and A-images take their definitional values
+    (pipelined_bicgsafe Eqns. 3.2/3.7/3.9/3.10):
+
+        r = R,  s = A r,  l = A t,  g = A y,  w = A u
+
+    The primary recurrence vectors ``p, u, t, y, z`` (and ``x``) are exact
+    either way and pass through, as do the unmasked columns.  Costs 4
+    block matvecs on the full block (ONE compiled program for any mask).
+    """
+    col = mask.astype(bool)[None, :]
+    out = dict(state)
+    out["r"] = jnp.where(col, R, state["r"])
+    out["s"] = jnp.where(col, bmv(R), state["s"])
+    out["l"] = jnp.where(col, bmv(state["t"]), state["l"])
+    out["g"] = jnp.where(col, bmv(state["y"]), state["g"])
+    out["w"] = jnp.where(col, bmv(state["u"]), state["w"])
+    return out
+
+
+def verify_columns(bmv: Callable, state: dict, B: jax.Array,
+                   mask: jax.Array):
+    """Check the masked (converged) columns against their true residual.
+
+    The recurred residual of a pipelined iteration drifts from the true
+    one ``B - A X`` (most visibly in float32), so a recurrence-converged
+    column may be far from its tolerance.  This computes every masked
+    column's true relative residual ``||b - A x|| / ||r_0||`` (one block
+    matvec); columns at or under their ``tol`` keep their converged flag,
+    the rest are un-converged and residual-replaced
+    (:func:`replace_residuals`) so that the next :func:`step_chunk`
+    resumes them from their true residual.  The replacement runs only
+    when some column needs it.
+
+    Returns ``(state, true_relres)``; ``true_relres`` is ``(m,)`` (entries
+    of unmasked columns are computed but carry no decision).
+    """
+    mask = mask.astype(bool)
+    R = B.astype(state["r"].dtype) - bmv(state["x"])
+    norm = jnp.sqrt(jnp.sum(R * R, axis=0))
+    n0 = state["norm_r0"]
+    # zero right-hand sides (norm_r0 == 0) converged at t=0 with x = 0
+    true = jnp.where(n0 > 0, norm / jnp.where(n0 > 0, n0, 1.0), norm
+                     ).astype(state["relres"].dtype)
+    redo = mask & ~(true <= state["tol"])      # NaN fails the check too
+
+    def replace(st):
+        out = replace_residuals(bmv, st, redo, R)
+        out["converged"] = st["converged"] & ~redo
+        out["relres"] = jnp.where(redo, true, st["relres"])
+        if "status" in st:
+            out["status"] = jnp.where(redo, SolveStatus.RUNNING.value,
+                                      st["status"])
+        return out
+
+    return jax.lax.cond(jnp.any(redo), replace, lambda st: st, state), true
 
 
 def _make_body(sub, bmv: Callable, config: SolverConfig,

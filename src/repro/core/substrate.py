@@ -109,6 +109,10 @@ class Substrate:
         """
         raise NotImplementedError
 
+    def check_dtype(self, dtype) -> None:
+        """Raise ``ValueError`` at bind time when this substrate cannot
+        compile the hot loop for operands of ``dtype`` on this backend."""
+
     def as_matvec(self, op):
         """Operator / matrix / callable -> matvec callable."""
         return linear_operator.as_matvec(op)
@@ -179,6 +183,15 @@ class PallasSubstrate(Substrate):
 
     name = "pallas"
     kernel_backed = True
+
+    def check_dtype(self, dtype):
+        from repro.kernels import ops
+        if dtype is not None and jnp.dtype(dtype) == jnp.float64 \
+                and not ops._interpret():
+            raise ValueError(
+                "substrate='pallas' cannot compile float64 kernels on TPU "
+                "(Mosaic is 32-bit); bind a float32 operator or "
+                "substrate='jnp'")
 
     def dots(self, pairs):
         return local_dots(pairs)

@@ -8,9 +8,9 @@ Kernels (each solver kernel has a multi-RHS block variant that streams
 (n, m) column tiles — see the *_batched entry points in each module):
   fused_dots       the paper's single fused inner-product phase (9 dots;
                    batched: one (9, m) partial block per pass)
-  spmv_ell         banded ELLPACK SpMV (TPU-native layout of the paper's
-                   CSR SpMV; batched: matrix/index tiles read once for
-                   all m columns)
+  spmv_ell         banded ELLPACK SpMV, streamed as shifted diagonals
+                   (TPU-native layout of the paper's CSR SpMV; batched:
+                   diagonal tiles read once for all m columns)
   fused_axpy       p-BiCGSafe's 10 vector updates in one HBM pass
                    (batched: per-column coefficients + the convergence
                    mask applied in-kernel)

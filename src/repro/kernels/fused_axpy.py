@@ -17,7 +17,8 @@ memory traffic of the phase is amortized over m right-hand sides.  The
 per-column convergence mask is applied *in-kernel*: frozen (converged /
 broken-down) columns write back their input tiles unchanged, which is
 what lets ``solve_batched`` freeze finished columns without a second
-masking pass over the ``(n, m)`` state.
+masking pass over the ``(n, m)`` state.  A single ``(n,)`` vector
+(``fused_axpy_pallas``) is the m = 1 case of the same kernel.
 """
 from __future__ import annotations
 
@@ -32,90 +33,21 @@ IN_ORDER = ("r", "p", "u", "t", "y", "z", "s", "l", "g", "w", "x", "As")
 OUT_ORDER = ("p", "o", "u", "q", "w", "t", "z", "y", "x", "r")
 
 
-def _kernel(scal_ref, r_ref, p_ref, u_ref, t_ref, y_ref, z_ref, s_ref,
-            l_ref, g_ref, w_ref, x_ref, As_ref,
-            p_o, o_o, u_o, q_o, w_o, t_o, z_o, y_o, x_o, r_o):
-    f32 = jnp.promote_types(r_ref.dtype, jnp.float32)
-    al = scal_ref[0, 0].astype(f32)
-    be = scal_ref[0, 1].astype(f32)
-    ze = scal_ref[0, 2].astype(f32)
-    et = scal_ref[0, 3].astype(f32)
-    r = r_ref[...].astype(f32)
-    p = p_ref[...].astype(f32)
-    u = u_ref[...].astype(f32)
-    t = t_ref[...].astype(f32)
-    y = y_ref[...].astype(f32)
-    z = z_ref[...].astype(f32)
-    s = s_ref[...].astype(f32)
-    l = l_ref[...].astype(f32)
-    g = g_ref[...].astype(f32)
-    w = w_ref[...].astype(f32)
-    x = x_ref[...].astype(f32)
-    As = As_ref[...].astype(f32)
-
-    p2 = r + be * (p - u)
-    o = s + be * t
-    u2 = ze * o + et * (y + be * u)
-    q = As + be * l
-    w2 = ze * q + et * (g + be * w)
-    t2 = o - w2
-    z2 = ze * r + et * z - al * u2
-    y2 = ze * s + et * y - al * w2
-    x2 = x + al * p2 + z2
-    r2 = r - al * o - y2
-
-    for ref, val in zip((p_o, o_o, u_o, q_o, w_o, t_o, z_o, y_o, x_o, r_o),
-                        (p2, o, u2, q, w2, t2, z2, y2, x2, r2)):
-        ref[...] = val.astype(ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_axpy_pallas(vecs: dict, scalars, *, block_rows: int = 256,
-                      interpret: bool = False) -> dict:
-    """vecs: dict of 12 equal-length vectors (IN_ORDER); scalars: (4,).
-    Returns dict of the 10 updated vectors (OUT_ORDER)."""
-    n = vecs["r"].shape[0]
-    dtype = vecs["r"].dtype
-    lane_rows = -(-n // LANES)
-    rows = -(-lane_rows // block_rows) * block_rows
-    padded = rows * LANES
-
-    def prep(v):
-        return jnp.pad(v, (0, padded - n)).reshape(rows, LANES)
-
-    args = [prep(vecs[k]) for k in IN_ORDER]
-    sdt = jnp.promote_types(dtype, jnp.float32)
-    scal = jnp.zeros((1, LANES), sdt).at[0, :4].set(
-        jnp.asarray(scalars, sdt))
-
-    vec_spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
-    outs = pl.pallas_call(
-        _kernel,
-        grid=(rows // block_rows,),
-        in_specs=[pl.BlockSpec((1, LANES), lambda i: (0, 0))]
-        + [vec_spec] * 12,
-        out_specs=[vec_spec] * 10,
-        out_shape=[jax.ShapeDtypeStruct((rows, LANES), dtype)] * 10,
-        interpret=interpret,
-    )(scal, *args)
-    return {k: o.reshape(-1)[:n] for k, o in zip(OUT_ORDER, outs)}
-
-
 # outputs with an input of the same name: their old tile is what a frozen
 # column must keep (o and q have no state counterpart — their values for
 # frozen columns are discarded by the solver's recurrence-tail masking)
 MASKED_OUT = ("p", "u", "w", "t", "z", "y", "x", "r")
 
 
-def _batched_kernel(scal_ref, r_ref, p_ref, u_ref, t_ref, y_ref, z_ref,
-                    s_ref, l_ref, g_ref, w_ref, x_ref, As_ref,
-                    p_o, o_o, u_o, q_o, w_o, t_o, z_o, y_o, x_o, r_o):
+def _kernel(scal_ref, r_ref, p_ref, u_ref, t_ref, y_ref, z_ref, s_ref,
+            l_ref, g_ref, w_ref, x_ref, As_ref,
+            p_o, o_o, u_o, q_o, w_o, t_o, z_o, y_o, x_o, r_o):
     f32 = jnp.promote_types(r_ref.dtype, jnp.float32)
-    al = scal_ref[0, 0].astype(f32)        # this column's coefficients
-    be = scal_ref[0, 1].astype(f32)
-    ze = scal_ref[0, 2].astype(f32)
-    et = scal_ref[0, 3].astype(f32)
-    mk = scal_ref[0, 4] != 0.0             # convergence mask (1 = advance)
+    al = scal_ref[0, 0, 0].astype(f32)     # this column's coefficients
+    be = scal_ref[0, 0, 1].astype(f32)
+    ze = scal_ref[0, 0, 2].astype(f32)
+    et = scal_ref[0, 0, 3].astype(f32)
+    mk = scal_ref[0, 0, 4] != 0.0          # convergence mask (1 = advance)
     r = r_ref[...].astype(f32)             # (1, block_rows, LANES) tiles
     p = p_ref[...].astype(f32)
     u = u_ref[...].astype(f32)
@@ -143,7 +75,7 @@ def _batched_kernel(scal_ref, r_ref, p_ref, u_ref, t_ref, y_ref, z_ref,
     old = {"p": p, "u": u, "w": w, "t": t, "z": z, "y": y, "x": x, "r": r}
     new = {"p": p2, "o": o, "u": u2, "q": q, "w": w2, "t": t2,
            "z": z2, "y": y2, "x": x2, "r": r2}
-    refs = dict(zip(("p", "o", "u", "q", "w", "t", "z", "y", "x", "r"),
+    refs = dict(zip(OUT_ORDER,
                     (p_o, o_o, u_o, q_o, w_o, t_o, z_o, y_o, x_o, r_o)))
     for k, ref in refs.items():
         val = jnp.where(mk, new[k], old[k]) if k in old else new[k]
@@ -164,9 +96,10 @@ def fused_axpy_batched_pallas(vecs: dict, scalars, mask=None, *,
 
     Layout mirrors ``fused_dots_batched_pallas``: each column is tiled as
     (rows, 128) with rows on the lane axis, the grid walks (column,
-    row-block), so per-column traffic matches the single-RHS kernel and
+    row-block), so per-column traffic matches the single-RHS case and
     small m does not force lane padding (an (n, m) minor-dim layout would
-    multiply HBM reads by 128/m).  The (n, m) <-> (m, rows, 128)
+    multiply HBM reads by 128/m).  Each column's coefficients and mask
+    sit in its own (1, 128) lane tile.  The (n, m) <-> (m, rows, 128)
     relayout at the call boundary is not free — XLA fuses it with the pad
     where it can, but a layout-conscious caller that keeps solver state
     column-major would avoid it entirely (noted as a perf follow-up; the
@@ -185,22 +118,35 @@ def fused_axpy_batched_pallas(vecs: dict, scalars, mask=None, *,
 
     args = [prep(vecs[k]) for k in IN_ORDER]
     sdt = jnp.promote_types(dtype, jnp.float32)
-    scal = jnp.zeros((m, LANES), sdt)
+    scal = jnp.zeros((m, 1, LANES), sdt)
     for j, coef in enumerate(scalars):
-        scal = scal.at[:, j].set(jnp.asarray(coef, sdt))
+        scal = scal.at[:, 0, j].set(jnp.asarray(coef, sdt))
     mk = (jnp.ones((m,), sdt) if mask is None
           else jnp.asarray(mask).astype(sdt))
-    scal = scal.at[:, 4].set(mk)
+    scal = scal.at[:, 0, 4].set(mk)
 
     vec_spec = pl.BlockSpec((1, block_rows, LANES), lambda j, i: (j, i, 0))
     outs = pl.pallas_call(
-        _batched_kernel,
+        _kernel,
         grid=(m, rows // block_rows),
-        in_specs=[pl.BlockSpec((1, LANES), lambda j, i: (j, 0))]
+        in_specs=[pl.BlockSpec((1, 1, LANES), lambda j, i: (j, 0, 0))]
         + [vec_spec] * 12,
         out_specs=[vec_spec] * 10,
         out_shape=[jax.ShapeDtypeStruct((m, rows, LANES), dtype)] * 10,
         interpret=interpret,
+        name="fused_axpy",
     )(scal, *args)
     return {k: o.reshape(m, -1)[:, :n].T
             for k, o in zip(OUT_ORDER, outs)}
+
+
+def fused_axpy_pallas(vecs: dict, scalars, *, block_rows: int = 256,
+                      interpret: bool = False) -> dict:
+    """vecs: dict of 12 equal-length vectors (IN_ORDER); scalars: (4,).
+    Returns dict of the 10 updated vectors (OUT_ORDER) — the m = 1 case
+    of :func:`fused_axpy_batched_pallas`."""
+    out = fused_axpy_batched_pallas(
+        {k: v[:, None] for k, v in vecs.items()},
+        tuple(jnp.reshape(jnp.asarray(c), (1,)) for c in scalars),
+        block_rows=block_rows, interpret=interpret)
+    return {k: v[:, 0] for k, v in out.items()}

@@ -7,17 +7,20 @@ separate dot kernels reading 18 operands.  The local partials this kernel
 emits are exactly what the solver's single ``psum`` reduces (Fig. 1.1 of
 the paper: local partial sums -> one global reduction).
 
-Layout: vectors are reshaped to (rows, 128) lanes; the grid walks row
-blocks sequentially and accumulates into the (1, 16)-padded output
-(first 9 entries meaningful).
+Layout: every right-hand-side column is laid out as (rows, 128) lane
+tiles, so an ``(n, m)`` block becomes ``(m, rows, 128)``; the grid walks
+(column, row-block) and accumulates into that column's ``(1, 16)`` output
+tile (first 9 entries meaningful).  A single ``(n,)`` vector is the
+m = 1 case of the same kernel.  Per-column memory traffic is one pass
+whatever m is, and the RHS axis is never padded up to a lane multiple
+(which for small m would multiply HBM reads by 128/m).
 
-``fused_dots_batched_pallas`` is the multi-RHS generalization: inputs are
-(n, m) column blocks (m right-hand sides) and the output is a (9, m)
-partial block — the m-column analogue of the same phase.  One HBM pass
-computes 9*m inner products, and the solver still reduces the whole block
-with ONE ``psum``: batching amortizes both the memory traffic and the
-reduction latency across right-hand sides (Krasnopolsky's multi-RHS
-argument applied to the pipelined communication model).
+``fused_dots_batched_pallas`` is the multi-RHS form: (n, m) blocks ->
+(9, m) partials.  One HBM pass computes 9*m inner products, and the
+solver still reduces the whole block with ONE ``psum``: batching
+amortizes both the memory traffic and the reduction latency across
+right-hand sides (Krasnopolsky's multi-RHS argument applied to the
+pipelined communication model).
 
 ``fused_dots_health_pallas`` / ``fused_dots_health_batched_pallas`` are
 the guarded variants (repro.resilience): two extra health rows — the
@@ -38,86 +41,29 @@ OUT_PAD = 16   # pad 9 -> 16 for clean layout
 HEALTH_ROWS = 11  # 9 solver dots + x.x + finiteness probe (still <= OUT_PAD)
 
 
-def _kernel(s_ref, y_ref, r_ref, t_ref, rs_ref, out_ref):
-    i = pl.program_id(0)
+def _kernel(*refs):
+    *in_refs, out_ref = refs
     acc = out_ref.dtype
-    s = s_ref[...].astype(acc)
-    y = y_ref[...].astype(acc)
-    r = r_ref[...].astype(acc)
-    t = t_ref[...].astype(acc)
-    rs = rs_ref[...].astype(acc)
-    partial = jnp.stack([
-        jnp.sum(s * s), jnp.sum(y * y), jnp.sum(s * y), jnp.sum(s * r),
-        jnp.sum(y * r), jnp.sum(rs * r), jnp.sum(rs * s), jnp.sum(rs * t),
-        jnp.sum(r * r)])
-    partial = jnp.pad(partial, (0, OUT_PAD - 9)).reshape(1, OUT_PAD)
+    s, y, r, t, rs, *rest = [ref[...].astype(acc) for ref in in_refs]
+    rows = [jnp.sum(s * s), jnp.sum(y * y), jnp.sum(s * y), jnp.sum(s * r),
+            jnp.sum(y * r), jnp.sum(rs * r), jnp.sum(rs * s),
+            jnp.sum(rs * t), jnp.sum(r * r)]
+    if rest:                              # guarded: the 2 health rows
+        (x,) = rest
+        rows += [jnp.sum(x * x), jnp.sum(s + y + t + rs + x)]
+    partial = jnp.pad(jnp.stack(rows), (0, OUT_PAD - len(rows)))
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)       # first row block of this column
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[...] += partial
+    out_ref[...] += partial.reshape(1, 1, OUT_PAD)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_dots_pallas(s, y, r, t, rs, *, block_rows: int = 256,
-                      interpret: bool = False) -> jax.Array:
-    """Returns the 9 fused dots (fp32).  Inputs: equal-length 1-D vectors."""
-    n = s.shape[0]
-    lane_rows = -(-n // LANES)              # ceil
-    rows = -(-lane_rows // block_rows) * block_rows
-    padded = rows * LANES
-
-    def prep(v):
-        return jnp.pad(v, (0, padded - n)).reshape(rows, LANES)
-
-    args = [prep(v) for v in (s, y, r, t, rs)]
-    grid = (rows // block_rows,)
-    out = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))] * 5,
-        out_specs=pl.BlockSpec((1, OUT_PAD), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (1, OUT_PAD), jnp.promote_types(s.dtype, jnp.float32)),
-        interpret=interpret,
-    )(*args)
-    return out[0, :9]
-
-
-def _batched_kernel(s_ref, y_ref, r_ref, t_ref, rs_ref, out_ref):
-    i = pl.program_id(1)                  # row block within this column
-    acc = out_ref.dtype
-    s = s_ref[...].astype(acc)            # (1, block_rows, LANES)
-    y = y_ref[...].astype(acc)
-    r = r_ref[...].astype(acc)
-    t = t_ref[...].astype(acc)
-    rs = rs_ref[...].astype(acc)
-    partial = jnp.stack([                 # the 9 dots of column j
-        jnp.sum(s * s), jnp.sum(y * y), jnp.sum(s * y), jnp.sum(s * r),
-        jnp.sum(y * r), jnp.sum(rs * r), jnp.sum(rs * s), jnp.sum(rs * t),
-        jnp.sum(r * r)])
-    partial = jnp.pad(partial, (0, OUT_PAD - 9)).reshape(OUT_PAD, 1)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += partial
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def fused_dots_batched_pallas(s, y, r, t, rs, *, block_rows: int = 256,
-                              interpret: bool = False) -> jax.Array:
-    """Multi-RHS fused dots: (n, m) inputs -> (9, m) partials (fp32+).
-
-    Rows stay on the lane axis exactly as in the 1-D kernel (each column
-    is laid out as (rows, 128) tiles) and the grid walks (column,
-    row-block), so the per-column memory traffic matches the single-RHS
-    kernel — no padding of the RHS axis up to a lane multiple, which for
-    small m would multiply HBM reads by 128/m.
-    """
-    n, m = s.shape
+def _dots_columns(*vecs, block_rows: int, interpret: bool) -> jax.Array:
+    """(n, m) blocks (5, or 6 for the guarded rows) -> (9 | 11, m)."""
+    n, m = vecs[0].shape
     lane_rows = -(-n // LANES)
     rows = -(-lane_rows // block_rows) * block_rows
     padded = rows * LANES
@@ -127,126 +73,49 @@ def fused_dots_batched_pallas(s, y, r, t, rs, *, block_rows: int = 256,
         return jnp.pad(v.T, ((0, 0), (0, padded - n))).reshape(
             m, rows, LANES)
 
-    args = [prep(v) for v in (s, y, r, t, rs)]
     vec_spec = pl.BlockSpec((1, block_rows, LANES), lambda j, i: (j, i, 0))
     out = pl.pallas_call(
-        _batched_kernel,
+        _kernel,
         grid=(m, rows // block_rows),
-        in_specs=[vec_spec] * 5,
-        out_specs=pl.BlockSpec((OUT_PAD, 1), lambda j, i: (0, j)),
+        in_specs=[vec_spec] * len(vecs),
+        out_specs=pl.BlockSpec((1, 1, OUT_PAD), lambda j, i: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (OUT_PAD, m), jnp.promote_types(s.dtype, jnp.float32)),
+            (m, 1, OUT_PAD), jnp.promote_types(vecs[0].dtype, jnp.float32)),
         interpret=interpret,
-    )(*args)
-    return out[:9, :]
+        name="fused_dots_health" if len(vecs) == 6 else "fused_dots",
+    )(*[prep(v) for v in vecs])
+    return out[:, 0, :9 if len(vecs) == 5 else HEALTH_ROWS].T
 
 
-def _health_kernel(s_ref, y_ref, r_ref, t_ref, rs_ref, x_ref, out_ref):
-    i = pl.program_id(0)
-    acc = out_ref.dtype
-    s = s_ref[...].astype(acc)
-    y = y_ref[...].astype(acc)
-    r = r_ref[...].astype(acc)
-    t = t_ref[...].astype(acc)
-    rs = rs_ref[...].astype(acc)
-    x = x_ref[...].astype(acc)
-    partial = jnp.stack([
-        jnp.sum(s * s), jnp.sum(y * y), jnp.sum(s * y), jnp.sum(s * r),
-        jnp.sum(y * r), jnp.sum(rs * r), jnp.sum(rs * s), jnp.sum(rs * t),
-        jnp.sum(r * r), jnp.sum(x * x), jnp.sum(s + y + t + rs + x)])
-    partial = jnp.pad(partial, (0, OUT_PAD - HEALTH_ROWS)).reshape(1, OUT_PAD)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += partial
+def fused_dots_pallas(s, y, r, t, rs, *, block_rows: int = 256,
+                      interpret: bool = False) -> jax.Array:
+    """Returns the 9 fused dots (fp32+).  Inputs: equal-length 1-D vectors."""
+    return _dots_columns(*[v[:, None] for v in (s, y, r, t, rs)],
+                         block_rows=block_rows, interpret=interpret)[:, 0]
 
 
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def fused_dots_batched_pallas(s, y, r, t, rs, *, block_rows: int = 256,
+                              interpret: bool = False) -> jax.Array:
+    """Multi-RHS fused dots: (n, m) inputs -> (9, m) partials (fp32+)."""
+    return _dots_columns(s, y, r, t, rs, block_rows=block_rows,
+                         interpret=interpret)
+
+
 def fused_dots_health_pallas(s, y, r, t, rs, x, *, block_rows: int = 256,
                              interpret: bool = False) -> jax.Array:
     """Guarded fused dots: 9 solver dots + 2 health rows (x.x, NaN/Inf
     probe) in one HBM pass — see ``kernels.ref.fused_dots_health`` for
-    the row layout.  Same tiling as ``fused_dots_pallas``; the padded
-    output still fits the (1, 16) tile, so the guarded phase costs one
-    extra VMEM operand and zero extra output traffic."""
-    n = s.shape[0]
-    lane_rows = -(-n // LANES)              # ceil
-    rows = -(-lane_rows // block_rows) * block_rows
-    padded = rows * LANES
-
-    def prep(v):
-        return jnp.pad(v, (0, padded - n)).reshape(rows, LANES)
-
-    args = [prep(v) for v in (s, y, r, t, rs, x)]
-    grid = (rows // block_rows,)
-    out = pl.pallas_call(
-        _health_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))] * 6,
-        out_specs=pl.BlockSpec((1, OUT_PAD), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (1, OUT_PAD), jnp.promote_types(s.dtype, jnp.float32)),
-        interpret=interpret,
-    )(*args)
-    return out[0, :HEALTH_ROWS]
+    the row layout.  The padded output still fits the (1, 16) tile, so
+    the guarded phase costs one extra VMEM operand and zero extra output
+    traffic."""
+    return _dots_columns(*[v[:, None] for v in (s, y, r, t, rs, x)],
+                         block_rows=block_rows, interpret=interpret)[:, 0]
 
 
-def _health_batched_kernel(s_ref, y_ref, r_ref, t_ref, rs_ref, x_ref,
-                           out_ref):
-    i = pl.program_id(1)                  # row block within this column
-    acc = out_ref.dtype
-    s = s_ref[...].astype(acc)            # (1, block_rows, LANES)
-    y = y_ref[...].astype(acc)
-    r = r_ref[...].astype(acc)
-    t = t_ref[...].astype(acc)
-    rs = rs_ref[...].astype(acc)
-    x = x_ref[...].astype(acc)
-    partial = jnp.stack([                 # 9 dots + 2 health rows, column j
-        jnp.sum(s * s), jnp.sum(y * y), jnp.sum(s * y), jnp.sum(s * r),
-        jnp.sum(y * r), jnp.sum(rs * r), jnp.sum(rs * s), jnp.sum(rs * t),
-        jnp.sum(r * r), jnp.sum(x * x), jnp.sum(s + y + t + rs + x)])
-    partial = jnp.pad(partial, (0, OUT_PAD - HEALTH_ROWS)).reshape(OUT_PAD, 1)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += partial
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def fused_dots_health_batched_pallas(s, y, r, t, rs, x, *,
                                      block_rows: int = 256,
                                      interpret: bool = False) -> jax.Array:
-    """Multi-RHS guarded dots: (n, m) inputs -> (11, m) partials.
-
-    The m-column analogue of ``fused_dots_health_pallas``: identical
-    (column, row-block) grid and lane layout as the unguarded batched
-    kernel, one extra operand (the previous iterate block ``x``), and the
-    (16, m) padded output carries 11 meaningful rows instead of 9 — the
-    guarded solve still issues exactly ONE reduction per iteration.
-    """
-    n, m = s.shape
-    lane_rows = -(-n // LANES)
-    rows = -(-lane_rows // block_rows) * block_rows
-    padded = rows * LANES
-
-    def prep(v):
-        # (n, m) -> (m, rows, LANES): column-major tiles, rows on lanes
-        return jnp.pad(v.T, ((0, 0), (0, padded - n))).reshape(
-            m, rows, LANES)
-
-    args = [prep(v) for v in (s, y, r, t, rs, x)]
-    vec_spec = pl.BlockSpec((1, block_rows, LANES), lambda j, i: (j, i, 0))
-    out = pl.pallas_call(
-        _health_batched_kernel,
-        grid=(m, rows // block_rows),
-        in_specs=[vec_spec] * 6,
-        out_specs=pl.BlockSpec((OUT_PAD, 1), lambda j, i: (0, j)),
-        out_shape=jax.ShapeDtypeStruct(
-            (OUT_PAD, m), jnp.promote_types(s.dtype, jnp.float32)),
-        interpret=interpret,
-    )(*args)
-    return out[:HEALTH_ROWS, :]
+    """Multi-RHS guarded dots: (n, m) inputs -> (11, m) partials — the
+    guarded solve still issues exactly ONE reduction per iteration."""
+    return _dots_columns(s, y, r, t, rs, x, block_rows=block_rows,
+                         interpret=interpret)

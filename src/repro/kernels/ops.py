@@ -25,7 +25,6 @@ short-circuited to one dense matmul.
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict
 
 import jax
@@ -40,7 +39,8 @@ from .fused_dots import (fused_dots_batched_pallas,
                          fused_dots_health_pallas, fused_dots_pallas)
 from .precond_apply import (block_jacobi_apply_batched_pallas,
                             block_jacobi_apply_pallas)
-from .spmv_ell import spmv_ell_batched_pallas, spmv_ell_pallas
+from .spmv_ell import (ell_offsets, spmv_ell_batched_pallas,
+                       spmv_ell_pallas)
 
 
 def _interpret() -> bool:
@@ -88,17 +88,18 @@ def spmv_ell(op, x) -> jax.Array:
     return spmv_ell_pallas(op.values, op.cols, x, interpret=_interpret())
 
 
-@functools.lru_cache(maxsize=64)
-def _banded_cache(key):  # pragma: no cover - trivial
-    return None
+#: the Pallas SpMV streams one shifted slice of x per distinct diagonal;
+#: ELL matrices with more diagonals than this stay on the jnp gather
+MAX_DIAGONALS = 32
 
 
 def ell_is_banded(op, block_rows: int = 512) -> bool:
-    rows = np.arange(op.n)[:, None]
-    cols = np.asarray(op.cols)
-    vals = np.asarray(op.values)
-    band = np.abs(np.where(vals != 0, cols - rows, 0)).max()
-    return bool(band < block_rows)
+    """True when the Pallas SpMV serves ``op``: every nonzero lies within
+    ``block_rows`` of the diagonal, on at most :data:`MAX_DIAGONALS`
+    distinct diagonals (host-side check on the concrete operator)."""
+    offsets = ell_offsets(op.values, op.cols)
+    return bool(np.abs(offsets).max() < block_rows
+                and offsets.size <= MAX_DIAGONALS)
 
 
 def block_jacobi_apply(inv_blocks, x) -> jax.Array:

@@ -30,9 +30,10 @@ from jax.experimental import pallas as pl
 
 
 def _group(nb: int, bs: int) -> int:
-    """Blocks per grid step: aim for ~64k elements of block tile."""
+    """Blocks per grid step: aim for ~64k elements of block tile, in a
+    multiple of 8 (the x tile's sublane axis) unless one step takes all."""
     g = max(1, 65536 // max(bs * bs, 1))
-    return min(g, nb)
+    return min(-(-g // 8) * 8, nb)
 
 
 def _kernel(blocks_ref, x_ref, y_ref):
@@ -64,6 +65,7 @@ def block_jacobi_apply_pallas(inv_blocks, x, *, interpret: bool = False
         out_specs=pl.BlockSpec((g, bs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb + pad, bs), x.dtype),
         interpret=interpret,
+        name="block_jacobi_apply",
     )(inv_blocks, xb)
     return y[:nb].reshape(n)
 
@@ -97,5 +99,6 @@ def block_jacobi_apply_batched_pallas(inv_blocks, x, *,
         out_specs=pl.BlockSpec((g, bs, m), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb + pad, bs, m), x.dtype),
         interpret=interpret,
+        name="block_jacobi_apply",
     )(inv_blocks, xb)
     return y[:nb].reshape(n, m)

@@ -3,6 +3,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+#: the solver's f32 contractions: a TPU's default f32 dot rounds operands
+#: to bf16, which breaks the Krylov recurrences
+HIGHEST = lax.Precision.HIGHEST
 
 
 def fused_dots(s, y, r, t, rs) -> jax.Array:
@@ -65,7 +70,7 @@ def spmv_ell(values, cols, x) -> jax.Array:
     independently (the oracle of the block-ELL kernel).
     """
     if x.ndim == 2:
-        return jnp.einsum("rk,rkm->rm", values, x[cols])
+        return jnp.einsum("rk,rkm->rm", values, x[cols], precision=HIGHEST)
     return jnp.sum(values * x[cols], axis=1)
 
 
@@ -117,15 +122,17 @@ def block_jacobi_apply(inv_blocks, x) -> jax.Array:
     if x.ndim == 2:
         xb = x.reshape(g, bs, x.shape[1])
         if nb == 1:
-            y = jnp.einsum("ij,gjm->gim", inv_blocks[0], xb)
+            y = jnp.einsum("ij,gjm->gim", inv_blocks[0], xb,
+                           precision=HIGHEST)
         else:
-            y = jnp.einsum("gij,gjm->gim", inv_blocks, xb)
+            y = jnp.einsum("gij,gjm->gim", inv_blocks, xb,
+                           precision=HIGHEST)
         return y.reshape(x.shape)
     xb = x.reshape(g, bs)
     if nb == 1:
-        y = xb @ inv_blocks[0].T
+        y = jnp.matmul(xb, inv_blocks[0].T, precision=HIGHEST)
     else:
-        y = jnp.einsum("gij,gj->gi", inv_blocks, xb)
+        y = jnp.einsum("gij,gj->gi", inv_blocks, xb, precision=HIGHEST)
     return y.reshape(n)
 
 

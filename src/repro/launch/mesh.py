@@ -6,8 +6,15 @@ touch jax device state (device count is locked on first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.core.compat import make_mesh
+
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with Auto axes: the sharded solvers place their
+    operands through ``shard_map`` specs and let eager code outside it
+    run on sharded results, which Explicit axes (the default) refuse."""
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

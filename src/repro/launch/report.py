@@ -8,9 +8,12 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-PEAK = 197e12
-HBM = 819e9
-LINK = 50e9
+from .peaks import chip_peaks
+
+_CHIP = chip_peaks()
+PEAK = _CHIP.bf16_flops
+HBM = _CHIP.hbm_bytes_per_s
+LINK = _CHIP.ici_link_bytes_per_s
 
 PARAMS_B = {
     "phi3-mini-3.8b": (3.7, 3.7), "qwen2.5-32b": (32.8, 32.8),

@@ -306,6 +306,10 @@ ENGINE_REQUESTS = REGISTRY.counter(
 ENGINE_RETRIES = REGISTRY.counter(
     "repro_engine_retries_total",
     "failed requests re-enqueued by the recovery policy")
+ENGINE_REPLACEMENTS = REGISTRY.counter(
+    "repro_engine_residual_replacements_total",
+    "recurrence-converged columns whose true residual missed their tol "
+    "at retirement, residual-replaced and resumed")
 ENGINE_QUEUE_DEPTH = REGISTRY.gauge(
     "repro_engine_queue_depth", "queued requests per operator",
     labels=("operator",))

@@ -440,12 +440,10 @@ class Capture:
 
     def finalize(self) -> Dict[str, Dict[str, str]]:
         """Lower + compile every noted program and merge the op maps.
-        Failures are non-fatal: the heuristic classifier still applies."""
+        These programs just ran, so a lowering or compile error here is
+        a defect and propagates."""
         for fn, structs, kwargs in self._programs:
-            try:
-                txt = fn.lower(*structs, **kwargs).compile().as_text()
-            except Exception:
-                continue
+            txt = fn.lower(*structs, **kwargs).compile().as_text()
             module, ops = hlo_op_map(txt)
             if module:
                 self.hlo_maps.setdefault(module, {}).update(ops)

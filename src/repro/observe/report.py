@@ -62,9 +62,8 @@ def run_smoke(out_dir: str, quick: bool = True) -> Dict[str, str]:
 
     Returns ``{artifact name: path}``.
     """
+    import jax
     import numpy as np
-
-    from jax.experimental import enable_x64
 
     import repro
     from repro.core import SolverConfig
@@ -77,7 +76,7 @@ def run_smoke(out_dir: str, quick: bool = True) -> Dict[str, str]:
 
     # paper protocol is fp64; scoped so an in-process caller (tests, a
     # notebook) gets its global x64 setting back afterwards
-    with enable_x64(True):
+    with jax.enable_x64(True):
         # -- leg 1: one traced session solve -----------------------------
         op, b, _ = M.poisson3d(nx)
         solver = repro.make_solver(
@@ -136,9 +135,8 @@ def run_profile(out_dir: str, quick: bool = True) -> Dict[str, str]:
 
     Returns ``{leg name: profile.json path}``.
     """
+    import jax
     import numpy as np
-
-    from jax.experimental import enable_x64
 
     import repro
     from repro.core import SolverConfig
@@ -150,7 +148,7 @@ def run_profile(out_dir: str, quick: bool = True) -> Dict[str, str]:
     n_req = 6 if quick else 24
     out: Dict[str, str] = {}
 
-    with enable_x64(True):
+    with jax.enable_x64(True):
         op, b, _ = M.poisson3d(nx)
         for sub in ("jnp", "pallas"):
             leg = f"session_{sub}"

@@ -13,8 +13,9 @@ phase, still no dependency edge to the in-flight matvec) and the
 3. apply the policy: on-trigger residual replacement for drifted
    columns, restart-from-current-x for broken-down / non-finite /
    stagnant columns, substrate degradation (pallas -> jnp, same state
-   pytree) after kernel-level failures, and per-column method fallback
-   once restarts are exhausted.
+   pytree) after a runtime fault of the compiled kernel program (a
+   program the backend refuses to compile is raised, never degraded),
+   and per-column method fallback once restarts are exhausted.
 
 Everything the driver does is logged in ``events`` (host-side list of
 dicts) and counted in the result state (``replacements`` / ``restarts``
@@ -157,6 +158,9 @@ class GuardedSolver:
         max_chunks = (1 + pol.max_restarts) * math.ceil(
             max(budget, 1) / chunk) + 1
 
+        # compile outside the fault handler below: a program the backend
+        # refuses is a defect to surface, not a runtime fault to degrade
+        self._active.compile_step_chunk(state, chunk)
         ci = 0
         degraded_once = False
         while ci < max_chunks:
@@ -235,9 +239,10 @@ class GuardedSolver:
         return prog(state, mask)
 
     def _degrade(self, exc, chunk: int) -> bool:
-        """Kernel-level failure: rebuild the step program on the jnp
-        substrate and continue from the SAME state pytree (it is a plain
-        dict of arrays — substrate-independent by construction)."""
+        """Runtime fault of the compiled step program (compile errors
+        surface before the chunk loop): rebuild the step program on the
+        jnp substrate and continue from the SAME state pytree (it is a
+        plain dict of arrays — substrate-independent by construction)."""
         if not self.policy.substrate_fallback:
             return False
         if getattr(self._active.sub, "name", None) == "jnp" \

@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.multirhs import _guard_init
+from repro.core.multirhs import _guard_init, replace_residuals
 from repro.core.types import SolveStatus
 
 
@@ -55,12 +55,7 @@ def replace_columns(bmv, state: dict, mask: jax.Array,
     """
     mask = mask.astype(bool)
     r_true = B.astype(state["r"].dtype) - bmv(state["x"])
-    out = dict(state)
-    out["r"] = _vec(mask, r_true, state["r"])
-    out["s"] = _vec(mask, bmv(r_true), state["s"])
-    out["l"] = _vec(mask, bmv(state["t"]), state["l"])
-    out["g"] = _vec(mask, bmv(state["y"]), state["g"])
-    out["w"] = _vec(mask, bmv(state["u"]), state["w"])
+    out = replace_residuals(bmv, state, mask, r_true)
     rdt = state["drift"].dtype
     m = mask.shape[0]
     out["drift"] = _sca(mask, jnp.zeros((m,), rdt), state["drift"])

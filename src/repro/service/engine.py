@@ -9,9 +9,12 @@ unification: empty slots ride along as frozen columns (per-column budget
 0), so the step program's shapes never change and nothing recompiles
 under load.
 
-Between chunks the engine retires finished columns — converged, broken
-down, past their per-request ``maxiter`` budget (enforced on-device by
-the per-column mask), or past their wall-clock ``deadline`` — and
+Between chunks the engine retires finished columns — converged (once
+their true residual ``b - A x`` is verified on the device: a column
+whose drifting recurrence claimed convergence too early is
+residual-replaced and keeps iterating), broken down, past their
+per-request ``maxiter`` budget (enforced on-device by the per-column
+mask), or past their wall-clock ``deadline`` — and
 refills the freed slots mid-flight by splicing fresh right-hand sides
 and reset per-column Krylov state into the live state pytree (the
 ``splice_step`` handle of the operator's bound
@@ -65,12 +68,20 @@ from .types import (RequestResult, RequestTelemetry, ServiceConfig,
                     SolveRequest)
 
 
+@jax.jit
+def _merge_columns(mask, new, old):
+    return jnp.where(mask[None, :], new, old)
+
+
 @dataclasses.dataclass
 class _Block:
     """One operator's resident (n, max_batch) block + host slot table."""
 
     state: dict
     slots: List[Optional[SolveRequest]]
+    #: the raw right-hand sides of the resident columns, on the device —
+    #: what a converged column's true residual is verified against
+    B: Optional[jax.Array] = None
     #: slots whose device column is still iterating but whose request was
     #: retired host-side (deadline) — they must be freeze-spliced
     orphans: set = dataclasses.field(default_factory=set)
@@ -321,9 +332,10 @@ class SolveEngine:
             blk = _Block(state=None, slots=[None] * m)
             self._blocks[name] = blk
             self._fill_vectors(entry, range(m), B, tolv, mitv)
+            blk.B = jnp.asarray(B)
             with _span("engine.init_fill", operator=name):
                 blk.state = entry.step_fn(
-                    entry.init_fn(jnp.asarray(B), jnp.asarray(tolv),
+                    entry.init_fn(blk.B, jnp.asarray(tolv),
                                   jnp.asarray(mitv)))
         else:
             free = [j for j in range(m) if blk.slots[j] is None]
@@ -336,9 +348,11 @@ class SolveEngine:
             if mask.any():
                 with _span("engine.splice_step", operator=name,
                            refills=int(mask.sum())):
+                    mask_d, B_d = jnp.asarray(mask), jnp.asarray(B)
                     blk.state = entry.splice_step_fn(
-                        blk.state, jnp.asarray(mask), jnp.asarray(B),
+                        blk.state, mask_d, B_d,
                         jnp.asarray(tolv), jnp.asarray(mitv))
+                    blk.B = _merge_columns(mask_d, B_d, blk.B)
             else:
                 with _span("engine.step", operator=name):
                     blk.state = entry.step_fn(blk.state)
@@ -370,6 +384,28 @@ class SolveEngine:
         trace_buf, trace_steps = None, 0
         if traced:
             trace_buf, trace_steps = got[k], int(got[k + 1])
+
+        # a converged column retires only once its true residual meets
+        # its tol (one block matvec and one more (m,) host read, in chunks
+        # where some request converged); a column the drifting recurrence
+        # fooled is residual-replaced on the device and stays resident
+        verify = np.array([req is not None and bool(conv[j])
+                           for j, req in enumerate(blk.slots)])
+        true_rr = None
+        if verify.any():
+            with _span("engine.verify", operator=name,
+                       columns=int(verify.sum())):
+                blk.state, true_d = entry.verify_fn(blk.state, blk.B,
+                                                    verify)
+                st = blk.state
+                got = jax.device_get((st["converged"], true_d)
+                                     + ((st["status"],) if guarded else ()))
+            conv, true_rr = got[0], got[1]
+            if guarded:
+                status_arr = got[2]
+            redo = int((verify & ~conv).sum())
+            if redo:
+                _metrics.ENGINE_REPLACEMENTS.inc(redo)
         recovery = self.scfg.recovery
         results: List[RequestResult] = []
         x_host = None
@@ -425,7 +461,9 @@ class SolveEngine:
                 # finite-output guarantee: a poisoned column never hands
                 # NaN back to the caller (the typed status says why)
                 xj = np.where(np.isfinite(xj), xj, 0.0)
-            rr_j = float(relres[j])
+            # a converged request reports the true residual it was
+            # verified with
+            rr_j = float(true_rr[j] if conv[j] else relres[j])
             trace = None
             if traced:
                 # per-column slice of the block's shared ring; spliced

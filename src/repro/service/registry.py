@@ -69,6 +69,7 @@ class RegisteredOperator:
         self.step_fn = lambda st: session.step_chunk(st, chunk)
         self.splice_step_fn = lambda st, mask, Bn, tolv, mitv: \
             session.splice_step(st, mask, Bn, tolv, mitv, chunk)
+        self.verify_fn = session.verify
 
     def __repr__(self):
         pc = getattr(self.precond, "name", None)

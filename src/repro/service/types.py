@@ -133,6 +133,11 @@ class RequestResult:
     policy).  ``trace`` is the request's per-iteration
     :class:`repro.observe.ConvergenceTrace` when the engine serves with
     ``ServiceConfig.trace_cap`` set (``None`` otherwise).
+
+    A ``converged`` request's ``relres`` is its true relative residual
+    ``||M^-1 (b - A x)|| / ||M^-1 b||`` as the engine verified it against
+    ``tol`` on the device before retiring it; otherwise ``relres`` is
+    the solver's recurred value.
     """
 
     rid: int

@@ -20,10 +20,11 @@ from repro.core import (SolverConfig, bicgstab_solve, gpbicg_solve,  # noqa: E40
 from repro.core import matrices as M  # noqa: E402
 from repro.core.distributed import (distributed_stencil_solve,  # noqa: E402
                                     distributed_stencil_solve_batched)
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def check(mesh_shape, axis_names, solver, op, b_grid, ref_iters, xt):
-    mesh = jax.make_mesh(mesh_shape, axis_names)
+    mesh = make_mesh(mesh_shape, axis_names)
     res = distributed_stencil_solve(solver, op, b_grid, mesh,
                                     config=SolverConfig(tol=1e-8))
     it = int(res.iterations)
@@ -49,7 +50,7 @@ def check_batched(mesh_shape, axis_names, op, b, substrate):
                          for k in keys[1:]], axis=1)
     cfg = SolverConfig(tol=1e-8, maxiter=2000)
     ref = solve_batched(op.matvec, B, config=cfg)
-    mesh = jax.make_mesh(mesh_shape, axis_names)
+    mesh = make_mesh(mesh_shape, axis_names)
     res = distributed_stencil_solve_batched(
         op, B.reshape(op.nx, op.ny, op.nz, m), mesh, config=cfg,
         substrate=substrate)
@@ -79,7 +80,7 @@ def check_batched_structure(op, b):
     m = 3
     B_grid = jnp.stack([b * (j + 1) for j in range(m)],
                        axis=1).reshape(op.nx, op.ny, op.nz, m)
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     jaxpr = jax.make_jaxpr(lambda BB: distributed_stencil_solve_batched(
         op, BB, mesh, config=SolverConfig(maxiter=10), jit=False))(B_grid)
     body = _find_while_body(jaxpr.jaxpr)
@@ -105,7 +106,7 @@ def check_precond_structure(op, b):
     still holds EXACTLY ONE psum (the (9,) stacked partials) and the
     psum's transitive inputs contain NO ppermute — the M^{-1}-apply rides
     inside the overlap window without adding or serializing collectives."""
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     bodies = {}
     for pc in (None, "block_jacobi"):
         jaxpr = jax.make_jaxpr(lambda bb: distributed_stencil_solve(
@@ -137,7 +138,7 @@ def check_precond_structure(op, b):
 def check_precond_numeric(mesh_shape, axis_names, op, b_grid, xt):
     """Shard-local block-Jacobi converges in <= the unpreconditioned
     iterations and still solves the ORIGINAL system."""
-    mesh = jax.make_mesh(mesh_shape, axis_names)
+    mesh = make_mesh(mesh_shape, axis_names)
     cfg = SolverConfig(tol=1e-8)
     plain = distributed_stencil_solve(pbicgsafe_solve, op, b_grid, mesh,
                                       config=cfg)
@@ -164,7 +165,7 @@ def check_guarded_structure(op, b):
     m = 3
     B_grid = jnp.stack([b * (j + 1) for j in range(m)],
                        axis=1).reshape(op.nx, op.ny, op.nz, m)
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     cfg = SolverConfig(maxiter=10, guard=True)
     jaxpr = jax.make_jaxpr(lambda BB: distributed_stencil_solve_batched(
         op, BB, mesh, config=cfg, jit=False))(B_grid)
@@ -193,7 +194,7 @@ def check_guarded_numeric(op, b):
     m = 2
     B = jnp.stack([b, 0.5 * b], axis=1)
     B_grid = B.reshape(op.nx, op.ny, op.nz, m)
-    mesh = jax.make_mesh((8,), ("rows",))
+    mesh = make_mesh((8,), ("rows",))
     plain = distributed_stencil_solve_batched(
         op, B_grid, mesh, config=SolverConfig(tol=1e-8, maxiter=2000))
     guard = distributed_stencil_solve_batched(

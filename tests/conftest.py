@@ -15,17 +15,10 @@ if SRC not in sys.path:
 
 
 def enable_x64(flag: bool = True):
-    """x64 context manager compatible across jax versions.
-
-    ``jax.enable_x64`` was removed in jax 0.4.37; the supported spelling is
-    ``jax.experimental.enable_x64``.  Test modules import this helper instead
-    of reaching into jax directly.
-    """
+    """x64 context manager (``jax.enable_x64``); test modules import it
+    from here."""
     import jax
-    if hasattr(jax, "enable_x64"):          # pragma: no cover - old jax
-        return jax.enable_x64(flag)
-    from jax.experimental import enable_x64 as _e
-    return _e(flag)
+    return jax.enable_x64(flag)
 
 
 @pytest.fixture

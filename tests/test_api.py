@@ -284,7 +284,7 @@ def test_custom_dot_reduce_skips_cache_and_counts_syncs(x64):
 def test_on_mesh_matches_legacy_distributed(x64):
     """session.on_mesh(mesh) == the legacy distributed drivers, bitwise,
     and repeat solves reuse the built shard_map program."""
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.distributed import (distributed_stencil_solve,
                                         distributed_stencil_solve_batched)
     op, b, _ = M.convection_diffusion(8, peclet=1.0)
@@ -320,7 +320,7 @@ def test_on_mesh_matches_legacy_distributed(x64):
 
 
 def test_on_mesh_requires_stencil_operator(x64):
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     op, _, _ = M.nonsym_dense(16)
     with pytest.raises(TypeError, match="Stencil7"):
         repro.make_solver("p-bicgsafe", op).on_mesh(
@@ -330,7 +330,7 @@ def test_on_mesh_requires_stencil_operator(x64):
 def test_on_mesh_rejects_custom_dot_reduce(x64):
     """The sharded driver supplies its own single-psum reduction; a
     session-bound dot_reduce must fail loudly, not be silently dropped."""
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     op, _, _ = M.convection_diffusion(8, peclet=1.0)
     session = repro.make_solver("p-bicgsafe", op,
                                 dot_reduce=lambda p: p)
@@ -342,7 +342,7 @@ def test_on_mesh_only_session_skips_global_precond_build(x64):
     """A session used only via .on_mesh never pays the global
     preconditioner build — the distributed binding rebuilds the name
     spec shard-locally (the legacy drivers' cost model, kept)."""
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     op, b, _ = M.convection_diffusion(8, peclet=1.0)
     session = repro.make_solver(
         "p-bicgsafe", M.convection_diffusion(8, peclet=1.0)[0],
@@ -480,7 +480,7 @@ def test_batched_paths_require_pbicgsafe(x64):
     """The batched/open-loop iteration IS p-BiCGSafe; a session bound to
     another method must fail loudly on those entry points instead of
     silently running the wrong algorithm."""
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     op, b, _ = M.convection_diffusion(8, peclet=1.0)
     session = repro.make_solver("bicgstab", op,
                                 config=SolverConfig(maxiter=200))

@@ -29,7 +29,7 @@ _CHILD = textwrap.dedent("""
     steps = 6 if mode == "first" else 12
     lm = None
     if int(n_dev) > 1:
-        from repro.core.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, int(n_dev) // 2), ("data", "model"))
         lm = LogicalMesh(mesh)
     tcfg = TrainConfig(steps=steps, ckpt_every=6, ckpt_dir=ckpt_dir,

@@ -83,6 +83,47 @@ def test_spmv_ell(n, stencil, dtype):
                                    rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+def test_spmv_ell_shifted_diagonals_cross_tiles(dtype):
+    """Diagonals that shift across lanes AND (rows, 128) tiles, with
+    unit-lane, lane-crossing and block-crossing offsets; single and
+    block kernels vs the gather oracle."""
+    n = 20000
+    offs = np.array([-1000, -129, -1, 0, 1, 128, 300])
+    rows = np.arange(n)[:, None]
+    cols = rows + offs[None, :]
+    out = (cols < 0) | (cols >= n)
+    cols = np.where(out, rows, cols).astype(np.int32)
+    with enable_x64(dtype == jnp.float64):
+        rng = np.random.default_rng(4)
+        vals = rng.standard_normal((n, len(offs)))
+        vals[out] = 0.0                  # ELL padding slots
+        values = jnp.asarray(vals, dtype)
+        x = rand(jax.random.PRNGKey(3), (n, 3), dtype)
+        want = ref.spmv_ell(values, jnp.asarray(cols), x)
+        got = spmv_ell_batched_pallas(values, cols, x, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-5)
+        got1 = spmv_ell_pallas(values, cols, x[:, 1], interpret=True)
+        np.testing.assert_allclose(np.asarray(got1), np.asarray(want[:, 1]),
+                                   rtol=1e-4, atol=1e-5)
+
+
+def test_ell_dispatch_needs_few_diagonals_in_band():
+    """The kernel path serves banded ELL on a bounded number of
+    diagonals; a matrix with more (or a wider band) keeps the gather."""
+    from repro.kernels import ops
+    n = 600
+    rows = np.arange(n)[:, None]
+    narrow = np.clip(rows + np.array([-2, -1, 0, 1, 2]), 0, n - 1)
+    op = ELLOperator(jnp.ones((n, 5)), jnp.asarray(narrow, np.int32), n)
+    assert ops.ell_is_banded(op)
+    rng = np.random.default_rng(0)
+    scattered = np.clip(rows + rng.integers(-200, 200, (n, 5)), 0, n - 1)
+    op = ELLOperator(jnp.ones((n, 5)), jnp.asarray(scattered, np.int32), n)
+    assert not ops.ell_is_banded(op)        # hundreds of diagonals
+
+
 @pytest.mark.parametrize("n", [100, 8192])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
 def test_fused_axpy(n, dtype):

@@ -1,5 +1,7 @@
 """Unit tests for smaller pieces: preconditioning, LR schedule, pipelined
 clipping, data prefetcher, solver mesh helper."""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -79,15 +81,15 @@ def test_ring_shift_is_exact_shift():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.core import compat
+        from repro.launch.mesh import make_mesh
         from repro.core.distributed import ring_shift
-        mesh = compat.make_mesh((4, 2), ("a", "b"))
+        mesh = make_mesh((4, 2), ("a", "b"))
         x = jnp.arange(8.0).reshape(8, 1)
         def f(x):
             fwd = ring_shift(x, ("a", "b"), (4, 2), True)
             bwd = ring_shift(x, ("a", "b"), (4, 2), False)
             return fwd, bwd
-        fwd, bwd = jax.jit(compat.shard_map(f, mesh=mesh,
+        fwd, bwd = jax.jit(jax.shard_map(f, mesh=mesh,
             in_specs=P(("a", "b")), out_specs=(P(("a", "b")),) * 2))(x)
         np.testing.assert_allclose(np.asarray(fwd).ravel(),
                                    [0,0,1,2,3,4,5,6])
@@ -99,3 +101,34 @@ def test_ring_shift_is_exact_shift():
                        capture_output=True, text=True, timeout=300)
     assert p.returncode == 0, p.stderr[-2000:]
     assert "RING OK" in p.stdout
+
+
+def test_compile_cache_dir_from_env_or_fixed_checkout_path(monkeypatch):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR when
+    set (nothing else is configured), otherwise one fixed path inside
+    the checkout."""
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_peak_table_refuses_unknown_device_kind():
+    from repro.launch.peaks import chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert v5e.hbm_bytes_per_s == 819e9 and v5e.bf16_flops == 197e12
+    with pytest.raises(KeyError, match="no published peak rates"):
+        chip_peaks("cpu")

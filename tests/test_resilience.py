@@ -309,6 +309,23 @@ def test_kernel_failure_degrades_substrate(x64):
     assert np.isfinite(np.asarray(res.x)).all()
 
 
+def test_compile_failure_raises_instead_of_degrading(x64):
+    """A step program the backend refuses to build is a defect: it
+    propagates, where a runtime fault of a compiled program degrades."""
+    from repro.core.substrate import PallasSubstrate
+
+    class Refused(PallasSubstrate):
+        def axpy_phase(self, vecs, scalars, mask=None):
+            raise RuntimeError("Mosaic refused the update kernel")
+
+    op, b, _ = _normalized_problem()
+    gs = _guarded(op, RecoveryPolicy(chunk=8), substrate=Refused(),
+                  config=SolverConfig(tol=1e-8, maxiter=100))
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        gs.solve(b)
+    assert not [e for e in gs.events if e["event"] == "substrate_degraded"]
+
+
 def test_kernel_failure_without_fallback_raises(x64):
     op, b, _ = _normalized_problem()
     inj = ChunkFaultInjector(fail_at=(0,))

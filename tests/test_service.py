@@ -11,6 +11,9 @@ The engine multiplexes heterogeneous requests onto one resident
   ``dot_reduce`` per iteration with NO dependency edge from the fused
   (9, m) reduction to the in-flight block matvec, on both substrates
   (contract probes via repro.analysis);
+* retirement — a request retires CONVERGED only once its true residual
+  ``b - A x`` meets its tol; a column the recurrence fooled is
+  residual-replaced and resumes;
 * caching — re-registering an operator with equal content reuses the
   built preconditioner AND the compiled step programs (fingerprint
   cache).
@@ -24,12 +27,13 @@ from jax import lax
 from repro.analysis import (BindingSpec, find_while_body as _find_while_body,
                             reduction_consumes_matvec, tag_matvec,
                             tag_reduce, trace_fn)
+from repro.api import make_solver
 from repro.core import SolverConfig, solve_batched
 from repro.core import matrices as M
 from repro.core._common import SyncCounter
 from repro.core.multirhs import init_state, step_chunk
 from repro.core.substrate import get_substrate
-from repro.core.types import identity_reduce
+from repro.core.types import SolveStatus, identity_reduce
 from repro.service import ServiceConfig, SolveEngine
 
 
@@ -272,3 +276,83 @@ def test_submit_validates_rhs_shape(x64):
     name = eng.register(M.poisson3d(8)[0], name="p")
     with pytest.raises(ValueError, match="shape"):
         eng.submit(name, jnp.ones((7,)))
+
+
+# ---------------------------------------------------------------------------
+# retirement: a converged column's true residual is verified
+# ---------------------------------------------------------------------------
+
+def test_verify_columns_replaces_only_the_fooled_column(x64):
+    """A column whose recurrence claims convergence while its true
+    residual misses tol is un-converged and residual-replaced; the other
+    columns pass through bitwise; resumed, it converges for real."""
+    op, _, _ = M.poisson3d(8)
+    sess = make_solver("p-bicgsafe", op, config=SolverConfig(tol=1e-8))
+    B = jnp.asarray(np.random.default_rng(3).standard_normal((op.n, 3)))
+    st = sess.step_chunk(sess.init(B), 400)
+    assert np.asarray(st["converged"]).all()
+    # what a drifted recurrence leaves behind: x off, r still "converged"
+    st["x"] = st["x"].at[:, 1].add(1e-3)
+    mask = np.ones(3, bool)
+    out, true = sess.verify(st, B, mask)
+    true = np.asarray(true)
+    assert (true[[0, 2]] <= 1e-8).all() and true[1] > 1e-8
+    np.testing.assert_array_equal(np.asarray(out["converged"]),
+                                  [True, False, True])
+    assert float(out["relres"][1]) == pytest.approx(true[1])
+    r1 = np.asarray(B[:, 1] - op.matvec(st["x"][:, 1]))
+    np.testing.assert_allclose(np.asarray(out["r"][:, 1]), r1,
+                               rtol=1e-12, atol=1e-15)
+    for k in ("x", "r", "s", "l", "g", "w", "p", "u"):
+        np.testing.assert_array_equal(np.asarray(out[k][:, [0, 2]]),
+                                      np.asarray(st[k][:, [0, 2]]))
+
+    out = sess.step_chunk(out, 400)
+    out, true = sess.verify(out, B, mask)
+    assert np.asarray(out["converged"]).all()
+    assert (np.asarray(true) <= 1e-8).all()
+    x1 = np.asarray(out["x"][:, 1])
+    assert np.linalg.norm(np.asarray(B[:, 1]) - np.asarray(
+        op.matvec(jnp.asarray(x1)))) <= 1e-8 * np.linalg.norm(B[:, 1])
+
+
+@pytest.mark.parametrize("guarded", [False, True])
+def test_engine_retires_converged_only_with_verified_residual(x64,
+                                                              guarded):
+    """A request whose recurrence converged with a bad iterate is not
+    handed back CONVERGED: the engine replaces its residual, keeps it
+    resident, and retires it once b - A x truly meets tol."""
+    from repro.observe import metrics as _metrics
+    from repro.resilience import RecoveryPolicy
+
+    op, _, _ = M.poisson3d(8)
+    scfg = ServiceConfig(max_batch=2, chunk=8, tol=1e-8, maxiter=400,
+                         recovery=RecoveryPolicy() if guarded else None)
+    eng = SolveEngine(scfg)
+    name = eng.register(op, name="p")
+    entry = eng.registry[name]
+    step = entry.step_fn
+    fooled = []
+
+    def step_then_drift(st):
+        st = step(st)
+        if not fooled and bool(st["converged"][0]):
+            fooled.append(True)           # once: column 0 drifts off
+            st = dict(st, x=st["x"].at[:, 0].add(1e-3))
+        return st
+
+    entry.step_fn = step_then_drift
+    rng = np.random.default_rng(5)
+    bs = [rng.standard_normal(op.n) for _ in range(2)]
+    rids = [eng.submit(name, b) for b in bs]
+    before = _metrics.ENGINE_REPLACEMENTS.value()
+    res = {r.rid: r for r in eng.run()}
+    assert fooled
+    assert _metrics.ENGINE_REPLACEMENTS.value() == before + 1
+    for rid, b in zip(rids, bs):
+        r = res[rid]
+        assert r.status == SolveStatus.CONVERGED and r.converged
+        true = np.linalg.norm(b - np.asarray(op.matvec(jnp.asarray(r.x))))
+        true /= np.linalg.norm(b)
+        assert true <= 1e-8
+        assert r.relres == pytest.approx(true, rel=1e-6)

@@ -269,7 +269,7 @@ def test_sharded_batched_single_psum_per_iter(x64, substrate, m, precond):
     count (the psum is mesh-size independent); the multi-device halo /
     dependency-edge structure is asserted in tests/_distributed_check.py
     and benchmarks/bench_overlap.py on 8 fake devices."""
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.core.distributed import distributed_stencil_solve_batched
 
     op, b, _ = M.convection_diffusion(8, peclet=1.0)
@@ -694,7 +694,7 @@ def test_session_sharded_batched_single_psum_per_iter(x64, substrate, m,
     the (9, m) block — matching the legacy distributed driver probe
     above (the session path must not add or split reductions)."""
     import repro
-    from repro.core.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     op, b, _ = M.convection_diffusion(8, peclet=1.0)
     B_grid = jnp.stack([b * (j + 1) for j in range(m)],

@@ -320,6 +320,12 @@ ENGINE_SLOT_OCCUPANCY = REGISTRY.gauge(
 ENGINE_CHUNK_SECONDS = REGISTRY.histogram(
     "repro_engine_chunk_seconds",
     "wall time of one engine chunk (dispatch + retirement read)")
+ENGINE_HOST_BYTES = REGISTRY.counter(
+    "repro_engine_host_bytes_total",
+    "bytes the solve engine moved between host and device (h2d: "
+    "admission blocks and per-column vectors; d2h: flag and verify "
+    "vectors, solution blocks), counted from host-known shapes",
+    labels=("direction",))
 REQUEST_QUEUE_WAIT = REGISTRY.histogram(
     "repro_request_queue_wait_seconds",
     "submit -> first resident in the block")

@@ -406,12 +406,13 @@ def analyze_timeline(src: Any,
 # ---------------------------------------------------------------------------
 
 class Capture:
-    """One profiling window: owns the jax.profiler dump dir, collects the
+    """One profiling window: owns the jax.profiler dump dir (None where
+    the window's profiler is someone else's: :class:`noting`), collects the
     jitted programs that executed inside it (noted by the session front
     door via :func:`active_capture`), and produces the HLO metadata maps.
     """
 
-    def __init__(self, out_dir: str):
+    def __init__(self, out_dir: Optional[str] = None):
         self.out_dir = out_dir
         self.perfetto_path: Optional[str] = None
         self._programs: List[Tuple[Any, Any, Dict[str, Any]]] = []
@@ -453,7 +454,7 @@ class Capture:
     def analyze(self, iterations: Optional[int] = None,
                 label: str = "") -> ProfileReport:
         self.finalize()
-        if self.perfetto_path is None:
+        if self.perfetto_path is None and self.out_dir:
             self.perfetto_path = find_perfetto_trace(self.out_dir)
         if self.perfetto_path is None:
             raise FileNotFoundError(
@@ -480,11 +481,31 @@ def active_capture() -> Optional[Capture]:
     return _ACTIVE[-1] if _ACTIVE else None
 
 
-class capture:
+class noting:
+    """Context manager: ``with noting() as cap: ...`` makes ``cap`` the
+    active capture, so that the programs run through the session front
+    door inside the block are noted on it, and starts no profiler.  A
+    caller that runs its own profiler enters this around its window and
+    calls ``cap.finalize()`` after it, for the ``{hlo_module:
+    {instruction: scope}}`` map of what ran."""
+
+    def __init__(self):
+        self.cap = Capture()
+
+    def __enter__(self) -> Capture:
+        _ACTIVE.append(self.cap)
+        return self.cap
+
+    def __exit__(self, *exc) -> None:
+        _ACTIVE.remove(self.cap)
+
+
+class capture(noting):
     """Context manager: ``with capture(out_dir) as cap: ...`` wraps the
     body in ``jax.profiler.trace`` and locates the emitted perfetto
     timeline on exit.  Programs run through the session front door inside
-    the window are noted on ``cap`` for HLO-map extraction.
+    the window are noted on ``cap`` (as under :class:`noting`) for
+    HLO-map extraction.
 
     Warm (compile + run once) before entering the window, or compilation
     events will dominate the timeline.
@@ -503,11 +524,10 @@ class capture:
         self._ctx = jax.profiler.trace(self.cap.out_dir,
                                        create_perfetto_trace=True)
         self._ctx.__enter__()
-        _ACTIVE.append(self.cap)
-        return self.cap
+        return super().__enter__()
 
     def __exit__(self, *exc) -> None:
-        _ACTIVE.remove(self.cap)
+        super().__exit__(*exc)
         self._ctx.__exit__(*exc)
         runs = sorted(set(glob.glob(os.path.join(
             self.cap.out_dir, "plugins", "profile", "*"))) - self._before)

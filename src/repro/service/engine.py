@@ -73,6 +73,23 @@ def _merge_columns(mask, new, old):
     return jnp.where(mask[None, :], new, old)
 
 
+def _put(*host):
+    """Host arrays onto the device, in order; their device bytes count
+    as ``h2d``."""
+    dev = tuple(jnp.asarray(a) for a in host)
+    _metrics.ENGINE_HOST_BYTES.inc(sum(d.nbytes for d in dev),
+                                   direction="h2d")
+    return dev
+
+
+def _get(*dev):
+    """Device arrays to the host in ONE transfer; their bytes count as
+    ``d2h``."""
+    _metrics.ENGINE_HOST_BYTES.inc(sum(d.nbytes for d in dev),
+                                   direction="d2h")
+    return jax.device_get(dev)
+
+
 @dataclasses.dataclass
 class _Block:
     """One operator's resident (n, max_batch) block + host slot table."""
@@ -322,36 +339,41 @@ class SolveEngine:
         # 1) admit + step, as ONE compiled program per chunk: either the
         # plain chunk step, or the fused splice-then-step when freed
         # slots are being refilled mid-flight (admission costs no extra
-        # dispatch or host round-trip)
+        # dispatch or host round-trip).  Spans: engine.admit (the host
+        # builds the admission block), engine.put (it goes to the
+        # device), then the dispatch alone
         if blk is None:
             if not q:
                 return []
-            B = np.zeros((entry.n, m), np_dtype)
-            tolv = np.full((m,), self.scfg.tol, np.float64)
-            mitv = np.zeros((m,), np.int32)
-            blk = _Block(state=None, slots=[None] * m)
-            self._blocks[name] = blk
-            self._fill_vectors(entry, range(m), B, tolv, mitv)
-            blk.B = jnp.asarray(B)
+            with _span("engine.admit", operator=name):
+                B = np.zeros((entry.n, m), np_dtype)
+                tolv = np.full((m,), self.scfg.tol, np.float64)
+                mitv = np.zeros((m,), np.int32)
+                blk = _Block(state=None, slots=[None] * m)
+                self._blocks[name] = blk
+                self._fill_vectors(entry, range(m), B, tolv, mitv)
+            with _span("engine.put", operator=name):
+                blk.B, tol_d, mit_d = _put(B, tolv, mitv)
             with _span("engine.init_fill", operator=name):
                 blk.state = entry.step_fn(
-                    entry.init_fn(blk.B, jnp.asarray(tolv),
-                                  jnp.asarray(mitv)))
+                    entry.init_fn(blk.B, tol_d, mit_d))
         else:
             free = [j for j in range(m) if blk.slots[j] is None]
             mask = np.zeros((m,), bool)
             if free and (q or blk.orphans):
-                B = np.zeros((entry.n, m), np_dtype)
-                tolv = np.zeros((m,), np.float64)
-                mitv = np.zeros((m,), np.int32)
-                self._fill_vectors(entry, free, B, tolv, mitv, mask=mask)
+                with _span("engine.admit", operator=name):
+                    B = np.zeros((entry.n, m), np_dtype)
+                    tolv = np.zeros((m,), np.float64)
+                    mitv = np.zeros((m,), np.int32)
+                    self._fill_vectors(entry, free, B, tolv, mitv,
+                                       mask=mask)
             if mask.any():
+                with _span("engine.put", operator=name):
+                    mask_d, B_d, tol_d, mit_d = _put(mask, B, tolv, mitv)
                 with _span("engine.splice_step", operator=name,
                            refills=int(mask.sum())):
-                    mask_d, B_d = jnp.asarray(mask), jnp.asarray(B)
                     blk.state = entry.splice_step_fn(
-                        blk.state, mask_d, B_d,
-                        jnp.asarray(tolv), jnp.asarray(mitv))
+                        blk.state, mask_d, B_d, tol_d, mit_d)
                     blk.B = _merge_columns(mask_d, B_d, blk.B)
             else:
                 with _span("engine.step", operator=name):
@@ -374,7 +396,7 @@ class SolveEngine:
         if traced:
             flags += [st["trace"], st["i"]]
         with _span("engine.retire", operator=name):
-            got = jax.device_get(tuple(flags))
+            got = _get(*flags)
         conv, brk, iters, relres, budget = got[:5]
         k = 5
         status_arr = None
@@ -398,8 +420,8 @@ class SolveEngine:
                 blk.state, true_d = entry.verify_fn(blk.state, blk.B,
                                                     verify)
                 st = blk.state
-                got = jax.device_get((st["converged"], true_d)
-                                     + ((st["status"],) if guarded else ()))
+                got = _get(st["converged"], true_d,
+                           *((st["status"],) if guarded else ()))
             conv, true_rr = got[0], got[1]
             if guarded:
                 status_arr = got[2]
@@ -455,7 +477,8 @@ class SolveEngine:
                 _metrics.ENGINE_RETRIES.inc()
                 continue
             if x_host is None:
-                x_host = np.asarray(st["x"])
+                with _span("engine.harvest", operator=name):
+                    x_host, = _get(st["x"])
             xj = x_host[:, j].copy()
             if not np.isfinite(xj).all():
                 # finite-output guarantee: a poisoned column never hands

@@ -363,6 +363,81 @@ def test_engine_emits_spans(x64):
     RECORDER.clear()
 
 
+def _drain_with_refills(enabled=True):
+    """Five float32 requests through two slots, so that the block is
+    filled once and refilled by splices; the spans and the host-byte
+    counter's growth come back with the results."""
+    from repro.observe.metrics import ENGINE_HOST_BYTES
+    op, b, _ = _problem(5)
+    eng = SolveEngine(ServiceConfig(max_batch=2, chunk=8, tol=1e-4,
+                                    maxiter=400))
+    name = eng.register(op)
+    rng = np.random.default_rng(7)
+    for _ in range(5):
+        eng.submit(name, rng.standard_normal(b.shape[0]).astype(np.float32))
+    before = {d: ENGINE_HOST_BYTES.value(direction=d)
+              for d in ("h2d", "d2h")}
+    RECORDER.clear()
+    RECORDER.enabled = enabled
+    try:
+        results = eng.run()
+    finally:
+        RECORDER.enabled = True
+    moved = {d: ENGINE_HOST_BYTES.value(direction=d) - before[d]
+             for d in before}
+    spans = RECORDER.spans()
+    RECORDER.clear()
+    return op.shape[0], results, spans, moved
+
+
+def test_engine_spans_the_host_round_trip():
+    n, results, spans, _ = _drain_with_refills()
+    assert len(results) == 5 and all(r.converged for r in results)
+    names = [s.name for s in spans]
+    assert {"engine.admit", "engine.put", "engine.harvest",
+            "engine.splice_step"} <= set(names)
+    # one put after each admission that spliced or filled, and none
+    # inside the dispatch spans
+    puts = [s for s in spans if s.name == "engine.put"]
+    assert len(puts) == names.count("engine.splice_step") \
+        + names.count("engine.init_fill")
+    for d in (s for s in spans if s.name in ("engine.splice_step",
+                                              "engine.init_fill")):
+        assert not any(d.start <= p.start < d.end for p in puts)
+    # one harvest in each chunk that retired a request, at most
+    assert 0 < names.count("engine.harvest") <= names.count("engine.retire")
+
+
+def test_engine_host_bytes_are_the_arithmetic():
+    """float32 blocks: a put moves the (n, m) block and the (m,) tol and
+    maxiter vectors (and the (m,) mask when it splices); each chunk reads
+    five (m,) flags (two bool, int32 iterations, float32 relres, int32
+    budget), a verify two (bool, float32), a harvest the (n, m) block."""
+    n, _, spans, moved = _drain_with_refills()
+    m = 2
+    count = {k: sum(s.name == k for s in spans) for k in (
+        "engine.init_fill", "engine.splice_step", "engine.retire",
+        "engine.verify", "engine.harvest")}
+    assert all(count.values())            # every term below is counted
+    assert moved["h2d"] == (count["engine.init_fill"] * (n * m * 4 + 8 * m)
+                            + count["engine.splice_step"]
+                            * (n * m * 4 + 9 * m))
+    assert moved["d2h"] == (count["engine.retire"] * 14 * m
+                            + count["engine.verify"] * 5 * m
+                            + count["engine.harvest"] * n * m * 4)
+
+
+def test_engine_results_bitwise_with_spans_on_and_off():
+    _, on, spans_on, moved_on = _drain_with_refills(enabled=True)
+    _, off, spans_off, moved_off = _drain_with_refills(enabled=False)
+    assert spans_on and not spans_off
+    assert moved_on == moved_off          # the counter is not a span
+    assert [r.rid for r in on] == [r.rid for r in off]
+    for a, b in zip(on, off):
+        assert _same(a.x, b.x)
+        assert a.iterations == b.iterations and a.relres == b.relres
+
+
 # ---------------------------------------------------------------------------
 # report CLI
 # ---------------------------------------------------------------------------

@@ -211,3 +211,34 @@ def test_render_mentions_headline(capsys=None):
     rep = P.analyze_timeline(_load("timeline_hidden.json"))
     text = rep.render()
     assert "overlap efficiency 1.000" in text
+
+
+# ---------------------------------------------------------------------------
+# noting: the capture's program notes without a profiler of its own
+# ---------------------------------------------------------------------------
+
+def test_noting_gives_the_capture_map_without_a_profiler(tmp_path):
+    """A caller with its own profiler enters ``noting``: the session
+    notes its programs there exactly as under ``capture``, so
+    ``finalize`` gives the same HLO map."""
+    import numpy as np
+
+    import repro
+    from repro.core import matrices as M
+
+    op, b, _ = M.poisson3d(5)
+    session = repro.make_solver("p-bicgsafe", op)
+    b = np.asarray(b, np.float32)
+    session.solve(b)                                  # warm
+    with P.noting() as cap:
+        assert P.active_capture() is cap
+        session.solve(b)
+    assert P.active_capture() is None
+    noted = cap.finalize()
+    with P.capture(str(tmp_path / "traced")) as cap2:
+        session.solve(b)
+    assert cap2.perfetto_path is not None
+    assert noted == cap2.finalize()
+    phases = {P.classify_op(op_, scope) for ops in noted.values()
+              for op_, scope in ops.items()}
+    assert {"matvec", "reduce", "axpy"} <= phases

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..precond.base import PrecondLike, preconditioned_system
 from ._common import (bicgsafe_coefficients, init_guess,
-                      pipelined_recurrence_tail, tree_select)
+                      pipelined_recurrence_tail)
 from .substrate import SubstrateLike, get_substrate
 from .types import (DotReduce, SolveResult, SolveStatus, SolverConfig,
                     classify_status, history_init, history_update,
@@ -99,31 +99,37 @@ def _pipelined_solve(matvec, b, x0, config, r0_star, dot_reduce,
 
         # --- blocked vector-update phase (Alg. 3.1 lines 23-32): one
         # substrate call covers all 10 recurrence updates (one fused HBM
-        # pass on the pallas substrate).
+        # pass on the pallas substrate).  On the iteration that stops, the
+        # result keeps x_i: the one select of the loop, fused into the x
+        # update.  Every other vector takes its new value unconditionally
+        # (the loop exits and nothing reads them), so each carry is
+        # written once an iteration.
+        stop = done | bad
         with jax.named_scope("repro.axpy"):
             upd = sub.axpy_phase(
                 dict(r=r, p=st["p"], u=st["u"], t=t_prev, y=y, z=st["z"],
                      s=s, l=st["l"], g=st["g"], w=st["w"], x=st["x"], As=As),
                 (alpha, beta, zeta, eta))
-        p, o, u, q, w = (upd[k] for k in ("p", "o", "u", "q", "w"))
-        t, z, y_next, x_next, r_next = (
-            upd[k] for k in ("t", "z", "y", "x", "r"))
+            x_next = jnp.where(stop, st["x"], upd["x"])
+        p, o, u, q, z = (upd[k] for k in ("p", "o", "u", "q", "z"))
 
         def pipe_tail():
             """Recurrence closure: MV #2 and the three recurred A-images."""
+            w = upd["w"]
             with jax.named_scope("repro.matvec"):
                 Aw = matvec(w)                        # MV #2 (A w_i)
             with jax.named_scope("repro.axpy"):
                 l_n, g_n, s_n = pipelined_recurrence_tail(
                     q, s, As, st["g"], Aw, alpha, zeta, eta)
-            return w, t, y_next, x_next, r_next, l_n, g_n, s_n
+            return w, upd["t"], upd["y"], upd["r"], l_n, g_n, s_n
 
         if not residual_replacement:
-            w, t, y_next, x_next, r_next, l, g_next, s_next = pipe_tail()
+            w, t, y_next, r_next, l, g_next, s_next = pipe_tail()
         else:
             # Alg. 4.1: every rr_epoch-th step replaces the recurred
-            # quantities with true matvec values (p, o, u, z keep their
-            # recurrence values — they are exact either way).
+            # quantities with true matvec values (p, o, u, z, x keep their
+            # recurrence values — they are exact either way, so x stays
+            # out of the cond).
             do_rr = ((st["i"] % config.rr_epoch) == 0) & (st["i"] > 0) \
                 & (st["i"] < config.rr_maxiter)
 
@@ -134,34 +140,28 @@ def _pipelined_solve(matvec, b, x0, config, r0_star, dot_reduce,
                     w_t = matvec(u)                   # true A u_i
                 t_t = o - w_t
                 y_t = zeta * s + eta * y - alpha * w_t
-                x_t = st["x"] + alpha * p + z
                 with jax.named_scope("repro.matvec"):
-                    r_t = b - matvec(x_t)
+                    r_t = b - matvec(x_next)
                     l_t = matvec(t_t)
                     g_t = matvec(y_t)
                     s_t = matvec(r_t)
-                return w_t, t_t, y_t, x_t, r_t, l_t, g_t, s_t
+                return w_t, t_t, y_t, r_t, l_t, g_t, s_t
 
-            w, t, y_next, x_next, r_next, l, g_next, s_next = jax.lax.cond(
+            w, t, y_next, r_next, l, g_next, s_next = jax.lax.cond(
                 do_rr, rr_branch, pipe_tail)
 
-        hist_i = history_update(st["hist"], st["i"], relres, config)
         new = dict(
             x=x_next, r=r_next, s=s_next, p=p, u=u, t=t, y=y_next, z=z,
             w=w, l=l, g=g_next,
             alpha=alpha, zeta=zeta, f=f,
-            i=st["i"] + 1, relres=relres,
-            converged=jnp.zeros((), bool), breakdown=jnp.zeros((), bool),
-            hist=hist_i)
-        stopped = dict(st)
-        stopped.update(relres=relres, converged=done, breakdown=bad & ~done,
-                       hist=hist_i)
+            i=jnp.where(stop, st["i"], st["i"] + 1), relres=relres,
+            converged=done, breakdown=bad & ~done,
+            hist=history_update(st["hist"], st["i"], relres, config))
         if config.trace_cap:
-            trace_i = _trace_row(st, dots, beta, relres, done, bad, config)
-            new["trace"] = stopped["trace"] = trace_i
-            new["trace_steps"] = stopped["trace_steps"] = \
-                st["trace_steps"] + 1
-        return tree_select(done | bad, stopped, new)
+            new["trace"] = _trace_row(st, dots, beta, relres, done, bad,
+                                      config)
+            new["trace_steps"] = st["trace_steps"] + 1
+        return new
 
     st = jax.lax.while_loop(cond, body, state)
     trace = {"buffer": st["trace"], "steps": st["trace_steps"]} \

@@ -4,9 +4,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (SOLVERS, SolverConfig, as_matvec, bicgstab_solve,
-                        gpbicg_solve, pbicgsafe_rr_solve, pbicgsafe_solve,
-                        pbicgstab_solve, ssbicgsafe2_solve)
+import repro
+from repro.analysis.jaxpr_tools import find_prim_eqns, find_while_body
+from repro.core import (SOLVERS, SolverConfig, Stencil7Operator, as_matvec,
+                        bicgstab_solve, gpbicg_solve, pbicgsafe_rr_solve,
+                        pbicgsafe_solve, pbicgstab_solve, ssbicgsafe2_solve)
 from repro.core import matrices as M
 from repro.core._common import SyncCounter
 from repro.core.types import identity_reduce
@@ -193,3 +195,70 @@ def test_breakdown_on_singular_system(x64):
     assert bool(res.breakdown)
     assert not bool(res.converged)
     assert np.isfinite(np.asarray(res.x)).all()
+
+
+# --- the single-vector p-BiCGSafe loop's stop: x_i is kept, every other
+# loop-carried vector is written once an iteration (no whole-state select)
+
+PIPELINED = ("p-bicgsafe", "p-bicgsafe-rr")
+
+
+def _small_stencil():
+    c = jnp.asarray([6.75, -1.5, -1.0, -1.25, -1.0, -1.0, -1.0], jnp.float32)
+    op = Stencil7Operator(c, 12, 10, 6)
+    b = jnp.asarray(np.random.default_rng(7).standard_normal(op.shape[0]),
+                    jnp.float32)
+    return op, b
+
+
+@pytest.mark.parametrize("method", PIPELINED)
+def test_tol_stop_returns_the_iterate_it_measured(method):
+    """A solve stopped by tol at iteration k returns x_k, k and relres_k:
+    x and k bitwise those of a solve capped at maxiter=k, relres bitwise
+    that of a solve capped at k + 1 (relres is measured at the top of an
+    iteration, so the capped-at-k solve reports relres_{k-1})."""
+    op, b = _small_stencil()
+
+    def solve(tol, maxiter):
+        return repro.make_solver(method, op, config=SolverConfig(
+            tol=tol, maxiter=maxiter, rr_epoch=5)).solve(b)
+
+    stopped = solve(1e-4, 500)
+    k = int(stopped.iterations)
+    assert bool(stopped.converged) and not bool(stopped.breakdown)
+    assert k > 5                              # past a replacement step
+    capped = solve(1e-30, k)
+    assert int(capped.iterations) == k and not bool(capped.converged)
+    np.testing.assert_array_equal(np.asarray(stopped.x),
+                                  np.asarray(capped.x))
+    assert float(stopped.relres) <= 1e-4
+    assert float(stopped.relres) == float(solve(1e-30, k + 1).relres)
+
+
+@pytest.mark.parametrize("method", PIPELINED)
+def test_zero_rhs_converges_at_step_zero(method):
+    op, b = _small_stencil()
+    res = repro.make_solver(method, op).solve(jnp.zeros_like(b))
+    assert bool(res.converged) and not bool(res.breakdown)
+    assert int(res.iterations) == 0
+    assert float(res.relres) == 0.0
+    assert not np.asarray(res.x).any()
+
+
+@pytest.mark.parametrize("substrate", ["jnp", "pallas"])
+@pytest.mark.parametrize("method", PIPELINED)
+def test_while_body_selects_at_most_one_vector(method, substrate):
+    """In the solve program's while body, cond branches included, at most
+    one select has a full (n,) vector output: the stop keeps x_i, and no
+    whole-state select carries the other vectors."""
+    op, b = _small_stencil()
+    n = b.shape[0]
+    cfg = SolverConfig(rr_epoch=5, record_history=True, trace_cap=8)
+    body = find_while_body(jax.make_jaxpr(
+        lambda v: SOLVERS[method](op, v, config=cfg, substrate=substrate))(b)
+        .jaxpr)
+    assert body is not None
+    assert find_prim_eqns(body, "cond") or method == "p-bicgsafe"
+    vector_selects = [e for e in find_prim_eqns(body, "select_n")
+                      if tuple(e.outvars[0].aval.shape) == (n,)]
+    assert len(vector_selects) <= 1, vector_selects

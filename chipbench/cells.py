@@ -5,16 +5,22 @@ its own, found from its name alone:
 - ``chipbench/configs/<config>.json`` (the ``file`` of the configuration);
 - ``chipbench/traffic/<mix>.json``;
 - ``chipbench/metrics/<metric>.py``, whose ``read(record)`` returns the
-  metric's value, or None where the run gave it nothing to read.
+  metric's value, or None where the run gave it nothing to read;
+- ``chipbench/operators/<kind>.py``, the configuration's ``operator.kind``
+  (see :func:`operator`).
 
-A later cell, configuration or metric is new files and new entries; no
-file here changes.
+A configuration's ``process_grid`` ([px, py, pz], one factor per grid
+axis) says how its grid is split over chips; the chips are the product.
+
+A later cell, configuration, operator or metric is new files and new
+entries; no file here changes.
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
+import math
 from pathlib import Path
 from typing import Callable, Dict, List
 
@@ -67,16 +73,37 @@ def load_cell(name: str) -> Cell:
                 traffic=mix, end_to_end=e2e, per_layer=layer)
 
 
-def metric_reader(name: str) -> Callable:
-    """``read`` of ``chipbench/metrics/<name>.py``."""
-    path = HERE / "metrics" / f"{name}.py"
+def chips(config: dict) -> int:
+    """The chips the configuration's ``process_grid`` spans."""
+    return math.prod(int(p) for p in config["process_grid"])
+
+
+def _load(group: str, name: str, what: str):
+    path = HERE / group / f"{name}.py"
     if not path.is_file():
-        raise KeyError(f"no reader for metric {name!r} ({path})")
+        raise KeyError(f"no {what} {name!r} ({path})")
     spec = importlib.util.spec_from_file_location(
-        f"chipbench.metrics.{name.replace('.', '_')}", path)
+        f"chipbench.{group}.{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str) -> Callable:
+    """``read`` of ``chipbench/metrics/<name>.py``."""
+    return _load("metrics", name, "reader for metric").read
+
+
+def operator(kind: str):
+    """The module ``chipbench/operators/<kind>.py`` of an operator kind.
+    It gives ``params(operator_cfg)``, what the two ``apply`` forms take
+    first; ``build(operator_cfg)``, the program's operator through its
+    public API in the configuration's ``dtype``; ``apply_f32(params, u)``,
+    the benchmark's jnp device check on the 3-D grid; and
+    ``apply_f64(params, u)``, the reference's float64 numpy operator,
+    which couples each x-plane only to its two neighbours and imports
+    nothing of the program."""
+    return _load("operators", kind, "operator kind")
 
 
 def read_metrics(metrics: List[dict], record) -> Dict[str, dict]:

@@ -7,11 +7,12 @@ many seeds (the lower readings) and the control (the upper readings).
 Runs the cell's window once per seed, all in one process, and prints each
 seed's compared numbers beside their limits, one JSON line per seed.  The
 control is the cell with the program computing in bfloat16, the precision
-below the configuration's float32: the operator's coefficients, the
-right-hand sides and so every vector of the solver and of the engine are
-bfloat16, while the benchmark's own checks stay float32 and the
-reference float64.  A sound benchmark reads ``correct`` false for it on
-every seed.  ``--program`` runs the cell as configured instead.
+below the configuration's float32: the operator (built by its kind's
+module in the configuration's ``dtype``), the right-hand sides and so
+every vector of the solver and of the engine are bfloat16, while the
+benchmark's own checks stay float32 and the reference float64.  A
+sound benchmark reads ``correct`` false for it on every seed.
+``--program`` runs the cell as configured instead.
 
 Without a TPU it exits 2.  The benchmark's own runs never run this.
 """

@@ -6,7 +6,9 @@ public API with its defaults, and warms every program the window calls.
 The window then drives the timed path for ``seconds``:
 
 - ``solve``: one right-hand side at a time through ``LinearSolver.solve``
-  (``DistributedSolver.solve`` where the configuration is sharded).  The
+  (``DistributedSolver.solve`` where the configuration's ``process_grid``
+  spans more than one chip: a ``("x", "y", "z")`` mesh of that shape, the
+  right-hand sides laid out in its blocks).  The
   benchmark checks every answer's true residual with a matvec of its own;
   an answer that misses its tol gets one refinement solve at the mix's
   ``refine_tol`` (``solve(b, x0=x)`` on one chip; on a mesh, whose solve
@@ -21,12 +23,14 @@ with scales drawn from the seed (see :mod:`chipbench.traffic`).
 
 After the window the answers still in flight are awaited, peak device
 memory is read, and a sample of the verified answers, drawn from the
-seed, is compared with the float64 reference (:mod:`chipbench.reference`).
+seed, is compared with the float64 reference (:mod:`chipbench.reference`),
+pulled to the host one at a time.
 The numbers compared, each with its limit, decide ``correct``.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 import shutil
 import statistics
 import sys
@@ -36,8 +40,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import reference, traffic as traffic_mod
-from .cells import Cell
+from . import cells, reference, traffic as traffic_mod
 from .floor import floor_bytes
 from .peaks import ChipPeaks
 
@@ -199,31 +202,30 @@ def _span(name: str):
 
 
 # ---------------------------------------------------------------------------
-# the benchmark's own device check: the configuration's stencil in jnp
+# the benchmark's own device check: the configuration's operator in jnp
 # ---------------------------------------------------------------------------
 
-def _stencil(c, u):
-    import jax.numpy as jnp
-
-    p = jnp.pad(u, 1)
-    return (c[0] * u + c[1] * p[:-2, 1:-1, 1:-1] + c[2] * p[2:, 1:-1, 1:-1]
-            + c[3] * p[1:-1, :-2, 1:-1] + c[4] * p[1:-1, 2:, 1:-1]
-            + c[5] * p[1:-1, 1:-1, :-2] + c[6] * p[1:-1, 1:-1, 2:])
-
-
-def _checks(grid, sharding):
+def _checks(operator_cfg: dict, sharding):
     """Jitted ``residual(c, b, x) -> b - A x`` (in b's type),
     ``relres(c, b, x) -> ||b - A x|| / ||b||`` and ``add(x, d)``, on
     vectors shaped like the program's (flat on one chip, the grid on a
-    mesh); the arithmetic is float32 whatever the program's type."""
+    mesh), with ``c`` the operator's float32 parameters; the arithmetic
+    is float32 whatever the program's type."""
     import jax
     import jax.numpy as jnp
 
-    shape = tuple(grid)
+    shape = tuple(operator_cfg["grid"])
+    apply_f32 = cells.operator(operator_cfg["kind"]).apply_f32
 
     def r32(c, b, x):
         b = b.reshape(shape).astype(jnp.float32)
-        return b, b - _stencil(c, x.reshape(shape).astype(jnp.float32))
+        x = x.reshape(shape).astype(jnp.float32)
+        if sharding is not None:
+            # the answer in b's blocks, whatever layout the solve left it
+            # in: the operator's halos then pass between neighbouring
+            # blocks, where a mixed layout would gather the whole grid
+            x = jax.lax.with_sharding_constraint(x, sharding)
+        return b, b - apply_f32(c, x)
 
     def residual(c, b, x):
         return r32(c, b, x)[1].reshape(b.shape).astype(b.dtype)
@@ -240,14 +242,8 @@ def _checks(grid, sharding):
 # set-up
 # ---------------------------------------------------------------------------
 
-def _operator(config: dict):
-    import jax.numpy as jnp
-
-    from repro.core import Stencil7Operator
-
-    o = config["operator"]
-    return Stencil7Operator(jnp.asarray(o["coeffs"], o["dtype"]),
-                            *o["grid"])
+def _operator(operator_cfg: dict):
+    return cells.operator(operator_cfg["kind"]).build(operator_cfg)
 
 
 def _session(config: dict, tol: float):
@@ -256,24 +252,28 @@ def _session(config: dict, tol: float):
 
     s = config["solver"]
     return repro.make_solver(
-        s["method"], _operator(config),
+        s["method"], _operator(config["operator"]),
         config=SolverConfig(tol=tol, maxiter=s["maxiter"],
                             rr_epoch=s["rr_epoch"]))
 
 
-def _mesh(devices, shards: int):
+def _mesh(devices, process_grid):
+    """The ``("x", "y", "z")`` mesh of ``process_grid`` over the first
+    devices, one mesh axis per grid axis."""
     import jax
     from jax.sharding import AxisType
 
-    return jax.make_mesh((shards,), ("rows",), axis_types=(AxisType.Auto,),
-                         devices=devices[:shards])
+    shape = tuple(int(p) for p in process_grid)
+    return jax.make_mesh(shape, ("x", "y", "z"),
+                         axis_types=(AxisType.Auto,) * 3,
+                         devices=devices[:math.prod(shape)])
 
 
 # ---------------------------------------------------------------------------
 # the solve entry
 # ---------------------------------------------------------------------------
 
-def _solve_entry(cell: Cell, seed: int, seconds: float, devices, tracer,
+def _solve_entry(cell: cells.Cell, seed: int, seconds: float, devices, tracer,
                  counter, t_start):
     import jax
     import jax.numpy as jnp
@@ -282,22 +282,23 @@ def _solve_entry(cell: Cell, seed: int, seconds: float, devices, tracer,
     cfg, mix = cell.config, cell.traffic
     o = cfg["operator"]
     grid, dtype = tuple(o["grid"]), o["dtype"]
-    shards = int(cfg.get("shards", 1))
+    chips = cells.chips(cfg)
     tols = sorted({float(t) for t in mix["tols"]})
     if len(tols) != 1:
         raise ValueError("a solve mix has one tol: the solver's tol is "
                          "static, so another would compile in the window")
     tol, refine_tol = tols[0], float(mix["refine_tol"])
     session = _session(cfg, tol)
-    c = jnp.asarray(o["coeffs"], jnp.float32)
-    if shards > 1:
-        sharding = NamedSharding(_mesh(devices, shards), P("rows"))
+    c = jnp.asarray(cells.operator(o["kind"]).params(o), jnp.float32)
+    if chips > 1:
+        sharding = NamedSharding(_mesh(devices, cfg["process_grid"]),
+                                 P("x", "y", "z"))
         dist = session.on_mesh(sharding.mesh)
         shape = grid
     else:
         sharding, dist, shape = None, None, (int(np.prod(grid)),)
     make_b = traffic_mod.make_rhs_fn(shape, dtype, sharding)
-    residual, relres, add = _checks(grid, sharding)
+    residual, relres, add = _checks(o, sharding)
 
     def solve(b):
         with _span("bench.solve"):
@@ -327,9 +328,12 @@ def _solve_entry(cell: Cell, seed: int, seconds: float, devices, tracer,
 
     say(f"set-up: session built at {time.perf_counter() - t_start:.3f} s")
     # warm every program the window calls, the refinement included, on
-    # right-hand sides the window never draws
+    # right-hand sides the window never draws; the check is warmed on the
+    # layouts of both the solve's and the refinement's answers, which
+    # differ on a mesh whose blocks are not the program's x-slabs
     b = make_b(traffic_mod.WARM_RHS, 1.0)
     x, _ = solve(b)
+    check(b, x)
     check(b, refine(b, x)[0])
 
     say(f"set-up: warm at {time.perf_counter() - t_start:.3f} s")
@@ -347,30 +351,37 @@ def _solve_entry(cell: Cell, seed: int, seconds: float, devices, tracer,
     window = time.perf_counter() - t0
     counter.armed = False
     tracer.close()
-    peak = _peak_bytes(devices[:shards])
-    checked = [(np.asarray(jax.device_get(make_b(req.rhs, req.scale))),
-                np.asarray(jax.device_get(x)), req.tol)
-               for req, x in sample.items]
+    peak = _peak_bytes(devices[:chips])
+
+    def checked():
+        # one answer and its b on the host at a time
+        for req, x in sample.items:
+            yield (np.asarray(jax.device_get(make_b(req.rhs, req.scale))),
+                   np.asarray(jax.device_get(x)), req.tol)
+
     record = Record(
         setup_s=t0 - t_start, window_s=window, peaks=None,
         floor_bytes=floor_bytes(grid, dtype, cfg["solver"]["method"],
-                                shards),
+                                chips),
         solves=recs)
     n_failed = sum(not r.verified for r in recs)
-    return record, len(recs), n_failed, 0, checked, peak
+    return record, len(recs), n_failed, 0, checked(), peak
 
 
 # ---------------------------------------------------------------------------
 # the serve entry
 # ---------------------------------------------------------------------------
 
-def _serve_entry(cell: Cell, seed: int, seconds: float, devices, tracer,
+def _serve_entry(cell: cells.Cell, seed: int, seconds: float, devices, tracer,
                  counter, t_start):
     import jax
 
     from repro.service import ServiceConfig, SolveEngine
 
     cfg, mix = cell.config, cell.traffic
+    if cells.chips(cfg) != 1:
+        raise ValueError("the serve entry runs its engine on one chip; "
+                         f"process_grid is {cfg['process_grid']}")
     o = cfg["operator"]
     grid, dtype = tuple(o["grid"]), o["dtype"]
     n = int(np.prod(grid))
@@ -385,7 +396,7 @@ def _serve_entry(cell: Cell, seed: int, seconds: float, devices, tracer,
     max_batch = int(mix["max_batch"])
     engine = SolveEngine(ServiceConfig(max_batch=max_batch,
                                        maxiter=cfg["solver"]["maxiter"]))
-    name = engine.register(_operator(cfg), name=cfg["name"])
+    name = engine.register(_operator(o), name=cfg["name"])
     tols = [float(t) for t in mix["tols"]]
     say(f"set-up: pool and engine built at "
         f"{time.perf_counter() - t_start:.3f} s")
@@ -456,7 +467,7 @@ def _serve_entry(cell: Cell, seed: int, seconds: float, devices, tracer,
     items = list(sample.items)
     if longest is not None and all(it is not longest[1] for it in items):
         items.append(longest[1])
-    checked = [(rhs(req), x, req.tol) for req, x in items]
+    checked = ((rhs(req), x, req.tol) for req, x in items)
     record = Record(setup_s=t0 - t_start, window_s=window, peaks=None,
                     requests=recs, chunks=chunks, max_batch=max_batch)
     n_failed = sum(not r.converged for r in recs)
@@ -474,7 +485,7 @@ def _peak_bytes(devices) -> int:
     return int(max(peaks))
 
 
-def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
+def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool, *,
              devices, peaks: ChipPeaks, t_start: float) -> Outcome:
     """Set up, measure and check one run of ``cell``."""
     from . import trace as trace_mod
@@ -493,12 +504,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         if trace:
             record.trace = trace_mod.summarize(
                 trace_mod.load_xplane(tdir),
-                [d.id for d in devices[:int(cfg.get("shards", 1))]])
+                [d.id for d in devices[:cells.chips(cfg)]])
     finally:
         if trace:
             shutil.rmtree(tdir, ignore_errors=True)
-    o = cfg["operator"]
-    ratios = [reference.relative_residual(o["coeffs"], o["grid"], b, x) / tol
+    ratios = [reference.relative_residual(cfg["operator"], b, x) / tol
               for b, x, tol in checked]
     say(f"checked {len(ratios)} verified answers against the float64 "
         f"reference; residual / tol: median "

@@ -21,7 +21,8 @@ def test_cell_resolves(workload):
     assert cell.per_layer, "every cell reports a per-layer metric"
     for m in cell.per_layer:
         assert m["moves"] in e2e
-    assert int(cell.config.get("shards", 1)) == cell.chips
+    assert len(cell.config["process_grid"]) == 3
+    assert cells.chips(cell.config) == cell.chips
 
 
 def test_every_metric_has_a_reader():

@@ -6,7 +6,7 @@ from chipbench import control
 from chipbench.tests.small import SERVE, SOLVE, cell_for, run
 
 
-@pytest.mark.parametrize("workload", [SOLVE, SERVE, "mesh"])
+@pytest.mark.parametrize("workload", [SOLVE, SERVE, "mesh", "mesh2x2"])
 def test_control_reads_not_correct(workload):
     cell = control.as_control(cell_for(workload))
     assert cell.config["operator"]["dtype"] == "bfloat16"

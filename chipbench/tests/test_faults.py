@@ -90,6 +90,7 @@ FAULTS = {
     SOLVE: [unchanged_state, altered_solve],
     SERVE: [unchanged_engine_state, half_batch, altered_answer],
     "mesh": [unchanged_state, no_exchange, altered_solve],
+    "mesh2x2": [unchanged_state, no_exchange],
 }
 
 

@@ -36,7 +36,8 @@ from repro.api import (DistributedSolver, LinearSolver, make_solver,
                        operator_fingerprint, solve)
 from repro.core import (SOLVERS, CSROperator, DenseOperator, ELLOperator,
                         Preconditioner, SolveResult, SolverConfig,
-                        Stencil7Operator, SUBSTRATES, get_substrate)
+                        Stencil7Operator, Stencil27Operator, SUBSTRATES,
+                        get_substrate)
 from repro.observe import ConvergenceTrace
 from repro.resilience import GuardedSolver, RecoveryPolicy, SolveStatus
 from repro.scenarios import (OperatorSpec, Scenario, register_operator_class,
@@ -49,6 +50,7 @@ __all__ = [
     # the vocabulary types the front door speaks
     "SolverConfig", "SolveResult", "SOLVERS",
     "DenseOperator", "CSROperator", "ELLOperator", "Stencil7Operator",
+    "Stencil27Operator",
     "Preconditioner",
     "SUBSTRATES", "get_substrate",
     # the scenario registry (repro.scenarios; make_solver(scenario=...))

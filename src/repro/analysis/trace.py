@@ -29,7 +29,7 @@ from jax import lax
 
 from repro.core import SOLVERS, SolverConfig
 from repro.core._deprecation import internal_use
-from repro.core.linear_operator import Stencil7Operator
+from repro.core.linear_operator import Stencil7Operator, Stencil27Operator
 from repro.core.multirhs import init_state, solve_batched, step_chunk
 from repro.core.substrate import get_substrate
 
@@ -180,7 +180,7 @@ def trace_binding(method: str,
         ``n=``).
       binding: ``"single"`` | ``"batched"`` | ``"open_loop"`` (the
         service-chunk program) | ``"mesh"`` (the sharded batched driver;
-        requires a :class:`Stencil7Operator` and ``mesh=``).
+        requires a stencil operator and ``mesh=``).
       guard: trace with ``SolverConfig.guard`` — the (11, m) fused
         phase on the bindings that support it (recorded as
         ``spec.guard_effective``).
@@ -205,8 +205,9 @@ def trace_binding(method: str,
     if binding == "mesh":
         if mesh is None:
             raise ValueError("binding='mesh' requires mesh=")
-        if not isinstance(operator, Stencil7Operator):
-            raise TypeError("binding='mesh' requires a Stencil7Operator")
+        if not isinstance(operator, (Stencil7Operator, Stencil27Operator)):
+            raise TypeError("binding='mesh' requires a Stencil7Operator or "
+                            "a Stencil27Operator")
         from repro.core.distributed import (build_stencil_solver,
                                             build_stencil_solver_batched)
         spec = BindingSpec(method=method, substrate=sub.name, binding="mesh",

@@ -59,7 +59,7 @@ import numpy as np
 
 from repro.core import SOLVERS
 from repro.core._deprecation import internal_use
-from repro.core.linear_operator import Stencil7Operator
+from repro.core.linear_operator import Stencil7Operator, Stencil27Operator
 from repro.core.multirhs import (init_state, result_from_state,
                                  splice_columns, step_chunk,
                                  verify_columns)
@@ -755,19 +755,21 @@ class DistributedSolver:
 
     Wraps :func:`repro.core.distributed.build_stencil_solver` /
     ``build_stencil_solver_batched``; the operator must be a
-    :class:`~repro.core.Stencil7Operator` (the row-sharded halo-exchange
-    format).  Name-spec preconditioners are rebuilt SHARD-LOCALLY from
-    ``precond_spec`` exactly as the legacy drivers do (zero extra
-    collectives — the single psum per iteration survives, asserted
-    through this binding in tests/test_substrate_parity.py).
+    :class:`~repro.core.Stencil7Operator` or
+    :class:`~repro.core.Stencil27Operator` (the block-sharded
+    halo-exchange formats).  Name-spec preconditioners are rebuilt
+    SHARD-LOCALLY from ``precond_spec`` exactly as the legacy drivers do
+    (zero extra collectives — the single psum per iteration survives,
+    asserted through this binding in tests/test_substrate_parity.py).
     """
 
     def __init__(self, session: LinearSolver, mesh,
                  shard_axes: Optional[Sequence[str]] = None):
-        if not isinstance(session.operator, Stencil7Operator):
+        if not isinstance(session.operator,
+                          (Stencil7Operator, Stencil27Operator)):
             raise TypeError(
-                "on_mesh requires a Stencil7Operator-bound session (the "
-                f"row-sharded halo format); got "
+                "on_mesh requires a Stencil7Operator- or Stencil27Operator-"
+                "bound session (the block-sharded halo formats); got "
                 f"{type(session.operator).__name__}")
         self.session = session
         self.mesh = mesh
@@ -792,6 +794,13 @@ class DistributedSolver:
               trace=None, profile=None) -> SolveResult:
         """Sharded single-RHS solve of the bound method on the mesh.
 
+        ``b_grid`` is the (nx, ny, nz) right-hand side.  Laid out by a
+        ``NamedSharding`` on this mesh it is solved in its own blocks
+        (no reshard; x comes back in them); otherwise the grid is split
+        in x-slabs over ``shard_axes``
+        (:func:`repro.core.distributed.grid_layout`).  One program is
+        compiled per layout.
+
         ``trace`` as in :meth:`LinearSolver.solve` — the ring buffer is
         built from psum-replicated scalars, so tracing adds no
         collective (still ONE psum per iteration, contract-verified).
@@ -800,18 +809,19 @@ class DistributedSolver:
         efficiency reads the psum/all-reduce time actually hidden under
         the halo-exchange matvec (report on ``session.last_profile``).
         """
+        from repro.core.distributed import build_stencil_solver, grid_layout
+
         s = self.session
         cfg = s._derive(tol, maxiter, trace)
+        layout = grid_layout(self.mesh, b_grid, self.shard_axes)
 
         def build():
-            from repro.core.distributed import build_stencil_solver
             return build_stencil_solver(
-                SOLVERS[s.method], s.operator, self.mesh,
-                shard_axes=self.shard_axes, config=cfg, substrate=s.sub,
-                precond=s.precond_spec)
+                SOLVERS[s.method], s.operator, self.mesh, layout=layout,
+                config=cfg, substrate=s.sub, precond=s.precond_spec)
 
         s._count_solve("mesh_solve")
-        key = ("dsolve", cfg)
+        key = ("dsolve", cfg, layout)
         if profile is not None:
             import os
 
@@ -832,8 +842,9 @@ class DistributedSolver:
 
     def solve_many(self, B_grid, *, tol=None, maxiter=None,
                    trace=None) -> SolveResult:
-        """Sharded batched solve: (nx, ny, nz, m) right-hand sides, ONE
-        (9, m) psum per iteration independent of m."""
+        """Sharded batched solve: (nx, ny, nz, m) right-hand sides in
+        x-slabs over ``shard_axes`` (whatever their layout), ONE (9, m)
+        psum per iteration independent of m."""
         s = self.session
         s._require_pbicgsafe("on_mesh(...).solve_many")
         cfg = s._derive(tol, maxiter, trace)
@@ -892,10 +903,10 @@ def make_solver(method: str = "p-bicgsafe", operator=None, *,
         ``make_solver(scenario="poisson-jacobi")`` is
         ``Scenario.bind()`` through the front door, and hits the same
         session cache.
-      operator: operator object (Dense/CSR/ELL/Stencil7), dense matrix,
-        or bare matvec callable.  Content-addressable operators make the
-        session cacheable; callables do not (name-spec preconditioners
-        also need an operator object).
+      operator: operator object (Dense/CSR/ELL/Stencil7/Stencil27), dense
+        matrix, or bare matvec callable.  Content-addressable operators
+        make the session cacheable; callables do not (name-spec
+        preconditioners also need an operator object).
       precond: ``None`` | name | :class:`~repro.precond.Preconditioner`.
         Built ONCE here; the distributed binding rebuilds name specs
         shard-locally.

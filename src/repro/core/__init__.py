@@ -17,7 +17,7 @@ Public API:
   - :func:`ssbicgsafe2_solve`     ssBiCGSafe2         (Alg. 2.3, 1 sync)
   - :func:`pbicgsafe_solve`       p-BiCGSafe          (Alg. 3.1, 1 overlapped sync)
   - :func:`pbicgsafe_rr_solve`    p-BiCGSafe-rr       (Alg. 4.1)
-* Operators: Dense/CSR/ELL/Stencil7.
+* Operators: Dense/CSR/ELL/Stencil7/Stencil27.
 * Problem generators: :mod:`repro.core.matrices`.
 * Distributed driver: :mod:`repro.core.distributed`.
 * Compute substrates: every solver takes ``substrate="jnp"|"pallas"``
@@ -46,7 +46,8 @@ import functools as _functools
 from ._deprecation import warn_legacy as _warn_legacy
 from .types import SolveResult, SolverConfig, identity_reduce
 from .linear_operator import (CSROperator, DenseOperator, ELLOperator,
-                              Stencil7Operator, as_matvec)
+                              Stencil7Operator, Stencil27Operator,
+                              as_matvec)
 from .substrate import (SUBSTRATES, JnpSubstrate, PallasSubstrate, Substrate,
                         get_substrate)
 from .bicgstab import bicgstab_solve
@@ -123,7 +124,8 @@ SOLVERS = {
 __all__ = [
     "SolveResult", "SolverConfig", "identity_reduce",
     "CSROperator", "DenseOperator", "ELLOperator",
-    "Stencil7Operator", "as_matvec", "preconditioned_matvec",
+    "Stencil7Operator", "Stencil27Operator", "as_matvec",
+    "preconditioned_matvec",
     "Preconditioner", "JacobiPreconditioner", "BlockJacobiPreconditioner",
     "NeumannPreconditioner", "SSORPreconditioner",
     "jacobi", "block_jacobi", "neumann", "ssor",

@@ -1,76 +1,162 @@
 """Distributed solver runtime: shard_map + halo exchange + single psum.
 
-Row-block domain decomposition over an arbitrary JAX mesh.  The grid's
-x-dimension is sharded over *all* mesh axes (flattened, row-major); halo
-exchange is a nearest-neighbour ``ppermute`` on the flattened logical ring,
-implemented recursively so it works on 1-, 2- ((data, model)) and
-3-axis ((pod, data, model)) production meshes — the wrap slab cascades to
-the next outer axis exactly like a carry.
+Block domain decomposition of the 3-D grid over an arbitrary JAX mesh.
+Each grid axis is split over the mesh axes its *layout* names
+(:func:`grid_layout`): a right-hand side laid out by a ``NamedSharding``
+on the mesh keeps its blocks (``P("x", "y", "z")`` on a 2x2x1 mesh gives
+each device a 2x2 block of whole z-columns); one without takes x-slabs,
+the grid's x-axis split over *all* mesh axes flattened row-major.
+
+One halo exchange serves every stencil operator (:func:`halo_pad`): it
+brings one plane across each split grid axis in turn -- x, then y on the
+x-extended block, then z -- so edges and corners arrive without diagonal
+messages, and the operator applies its stencil to the padded block
+(``apply_padded``).  A plane travels by :func:`ring_shift`, a
+nearest-neighbour ``ppermute`` on the flattened ring of the axis's mesh
+axes, implemented recursively so it works on 1-, 2- ((data, model)) and
+3-axis ((pod, data, model)) production meshes — the wrap slab cascades
+to the next outer axis exactly like a carry.
 
 The inner-product phases of the solvers call ``dot_reduce`` once per phase;
-here that is **one ``lax.psum`` of the stacked partials over the whole
-mesh** — the paper's single global reduction.  Because p-BiCGSafe's dots do
-not consume the in-flight matvec, the lowered HLO contains no dependency
-path from that all-reduce to the halo ppermutes / stencil compute, which is
-what lets the XLA latency-hiding scheduler overlap them (verified
-structurally in benchmarks/bench_overlap.py).
+here that is **one ``lax.psum`` of the stacked partials over the split
+mesh axes** — the paper's single global reduction.  Because p-BiCGSafe's
+dots do not consume the in-flight matvec, the lowered HLO contains no
+dependency path from that all-reduce to the halo ppermutes / stencil
+compute, which is what lets the XLA latency-hiding scheduler overlap them
+(verified structurally in benchmarks/bench_overlap.py).
 
 :func:`distributed_stencil_solve_batched` extends the same decomposition
-to multi-RHS blocks: the (n, m) block is row-sharded, the halo exchange
-carries all m columns in one ppermute cascade, and the single psum now
-reduces the (9, m) partial block — communication per iteration is
-independent of m, and the overlap property survives (same structural
+to multi-RHS blocks: the (n, m) block is sharded in x-slabs, the halo
+exchange carries all m columns in one ppermute cascade, and the single
+psum now reduces the (9, m) partial block — communication per iteration
+is independent of m, and the overlap property survives (same structural
 proof, batched entry).
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Sequence, Tuple
+import math
+from typing import Callable, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..precond.base import PrecondLike, resolve_precond
 from . import _deprecation
-from .linear_operator import Stencil7Operator
+from .linear_operator import Stencil7Operator, Stencil27Operator
 from .types import SolveResult, SolverConfig
 
+StencilOperator = Union[Stencil7Operator, Stencil27Operator]
+#: the mesh axes each grid axis (x, y, z) is split over
+Layout = Tuple[Tuple[str, ...], Tuple[str, ...], Tuple[str, ...]]
+#: per grid axis, the (mesh axes, their sizes) of its split, both empty
+#: where the axis is whole on every device
+Split = Tuple[Tuple[Tuple[str, ...], Tuple[int, ...]], ...]
+GRID_AXES = ("x", "y", "z")
 
-def _shard_local_precond(precond: PrecondLike, c: jax.Array,
-                         local_shape: Tuple[int, int, int]):
-    """Resolve ``precond`` against the LOCAL slab operator.
 
-    Name specs build from the shard's own ``(nxl, ny, nz)`` stencil
-    operator, so every preconditioner is communication-free by
-    construction: its arrays describe one slab and its apply touches no
-    mesh axis (the per-iteration psum count is therefore unchanged —
-    asserted in tests/_distributed_check.py).  For ``"jacobi"`` and
-    ``"block_jacobi"`` this is *exact* (the diagonal is constant and
-    z-line blocks never straddle x-slab boundaries); for ``"neumann"``
-    and ``"ssor"`` it is the shard-local (zero-Dirichlet at slab
+def _shard_local_precond(precond: PrecondLike, local_op: StencilOperator):
+    """Resolve ``precond`` against the LOCAL block operator.
+
+    Name specs build from the shard's own block operator (``local_op``,
+    the global one's coefficients on the block's shape), so every
+    preconditioner is communication-free by construction: its arrays
+    describe one block and its apply touches no mesh axis (the
+    per-iteration psum count is therefore unchanged — asserted in
+    tests/_distributed_check.py).  For ``"jacobi"`` this is *exact* (the
+    diagonal is constant), as is ``"block_jacobi"`` where z is not split
+    (z-line blocks never straddle a block boundary); for ``"neumann"``
+    and ``"ssor"`` it is the shard-local (zero-Dirichlet at block
     boundaries) additive-Schwarz flavor of the global preconditioner —
-    still a fixed linear M^{-1}, just a slightly weaker one.
+    still a fixed linear M^{-1}, just a slightly weaker one.  A
+    :class:`Stencil27Operator` takes ``"jacobi"`` only.
 
     A :class:`~repro.precond.Preconditioner` instance is passed through
-    untouched; its arrays must already be local-slab sized (or
+    untouched; its arrays must already be local-block sized (or
     shard-shape-free, like a shared (1, bs, bs) block).
     """
     if not isinstance(precond, str):
         return precond
-    local_op = Stencil7Operator(c, *local_shape)
+    if isinstance(local_op, Stencil27Operator) and precond != "jacobi":
+        raise ValueError(
+            f"precond={precond!r} has no shard-local form for a "
+            "Stencil27Operator on a mesh; use None or 'jacobi'")
     return resolve_precond(precond, local_op)
 
 
 # ---------------------------------------------------------------------------
-# flattened-ring halo exchange
+# decomposition
 # ---------------------------------------------------------------------------
 
 def _axis_sizes(mesh: Mesh, axes: Sequence[str]) -> Tuple[int, ...]:
     return tuple(mesh.shape[a] for a in axes)
 
+
+def grid_layout(mesh: Mesh, b=None,
+                shard_axes: Optional[Sequence[str]] = None) -> Layout:
+    """The mesh axes each grid axis is split over.
+
+    ``shard_axes`` given: x-slabs over those axes.  Otherwise ``b``'s own
+    blocks, where it carries a ``NamedSharding`` on ``mesh`` that splits
+    a grid axis; otherwise x-slabs over every mesh axis.
+    """
+    if shard_axes is None:
+        sharding = getattr(b, "sharding", None)
+        if isinstance(sharding, NamedSharding) and sharding.mesh == mesh:
+            spec = (tuple(sharding.spec) + (None,) * 3)[:3]
+            layout = tuple(() if s is None else (s,) if isinstance(s, str)
+                           else tuple(s) for s in spec)
+            if any(layout):
+                return layout
+        shard_axes = mesh.axis_names
+    return (tuple(shard_axes), (), ())
+
+
+def decompose(mesh: Mesh, layout: Layout, grid: Sequence[int]
+              ) -> Tuple[Split, Tuple[int, int, int]]:
+    """``(split, local_shape)`` of ``grid`` laid out by ``layout``.
+
+    Raises ``ValueError`` where a grid axis does not divide by the
+    number of its shards.
+    """
+    split, local = [], []
+    for name, axes, n in zip(GRID_AXES, layout, grid):
+        sizes = _axis_sizes(mesh, axes)
+        shards = math.prod(sizes)
+        if n % shards:
+            raise ValueError(
+                f"grid axis {name} has {n} points, which do not split over "
+                f"its {shards} shards (mesh axes {axes})")
+        split.append((tuple(axes), sizes) if shards > 1 else ((), ()))
+        local.append(n // shards)
+    return tuple(split), tuple(local)
+
+
+def _spec(layout: Layout) -> P:
+    return P(*[(axes[0] if len(axes) == 1 else axes) if axes else None
+               for axes in layout])
+
+
+def _note_halo_bytes(split: Split, local_shape: Sequence[int],
+                     itemsize: int, columns: int = 1) -> None:
+    """Set ``repro_mesh_halo_bytes{axis}``: the bytes one device sends
+    per matvec along each grid axis — a plane of the block (extended
+    along the axes exchanged before it) to each neighbour it has there,
+    one where the axis has two shards, none where it is whole."""
+    from repro.observe.metrics import MESH_HALO_BYTES
+    ext = list(local_shape)
+    for d, (axes, sizes) in enumerate(split):
+        planes = min(2, math.prod(sizes) - 1) if axes else 0
+        MESH_HALO_BYTES.set(planes * math.prod(ext) // ext[d] * columns
+                            * itemsize, axis=GRID_AXES[d])
+        ext[d] += 2
+
+
+# ---------------------------------------------------------------------------
+# halo exchange
+# ---------------------------------------------------------------------------
 
 def ring_shift(x: jax.Array, axes: Sequence[str], sizes: Sequence[int],
                forward: bool) -> jax.Array:
@@ -99,41 +185,41 @@ def ring_shift(x: jax.Array, axes: Sequence[str], sizes: Sequence[int],
     return within + wrap
 
 
-def halo_stencil_matvec(c: jax.Array, u_flat: jax.Array,
-                        local_shape: Tuple[int, int, int],
-                        axes: Sequence[str], sizes: Sequence[int]) -> jax.Array:
-    """7-point stencil matvec on the local x-slab with ring halo exchange.
+def halo_pad(u: jax.Array, split: Split) -> jax.Array:
+    """The local block ``u`` (grid axes first, then any column axes) with
+    one plane more on each side of each grid axis: the neighbouring
+    block's plane across a split axis, zeros at the global boundary and
+    along whole axes.  Axes are exchanged in turn, each on the block
+    already extended along the ones before it, so edges and corners
+    arrive without diagonal messages."""
+    for d, (axes, sizes) in enumerate(split):
+        n = u.shape[d]
+        first = lax.slice_in_dim(u, 0, 1, axis=d)
+        last = lax.slice_in_dim(u, n - 1, n, axis=d)
+        if axes:
+            with jax.named_scope("repro.halo"):
+                lo = ring_shift(last, axes, sizes, forward=True)
+                hi = ring_shift(first, axes, sizes, forward=False)
+        else:
+            lo = hi = jnp.zeros_like(first)
+        u = jnp.concatenate([lo, u, hi], axis=d)
+    return u
 
-    Communication: two 1-slab ppermute cascades (up & down neighbours) of
-    ny*nz elements each — the O(surface) cost that the paper's SpMV hides
-    the O(1) reduction message behind.
 
-    ``u_flat`` may be a multi-RHS block ``(nxl*ny*nz, m)``: the stencil and
-    the halo ppermutes carry the trailing column axis along, so one halo
-    cascade serves all m right-hand sides (the per-column communication
-    cost is amortized m-fold, mirroring the batched reduction).
+def halo_matvec(local_op: StencilOperator, split: Split,
+                u_flat: jax.Array) -> jax.Array:
+    """The stencil matvec of one device's block: halo exchange, then the
+    operator's stencil on the padded block.
+
+    Communication: one plane to each neighbour along each split axis —
+    the O(surface) cost that the paper's SpMV hides the O(1) reduction
+    message behind.  ``u_flat`` may be a multi-RHS block
+    ``(n_local, m)``: the halo ppermutes carry the trailing column axis
+    along, so one exchange serves all m right-hand sides.
     """
-    nxl, ny, nz = local_shape
-    u = u_flat.reshape(nxl, ny, nz, *u_flat.shape[1:])
-
-    # x-direction halos from the flattened ring
-    top = u[-1:]      # sent forward: becomes receiver's u[x-1] slab
-    bot = u[:1]       # sent backward: becomes receiver's u[x+1] slab
-    halo_lo = ring_shift(top, axes, sizes, forward=True)    # u[i-1] at i=0
-    halo_hi = ring_shift(bot, axes, sizes, forward=False)   # u[i+1] at i=nxl-1
-
-    um = jnp.concatenate([halo_lo, u[:-1]], axis=0)
-    up = jnp.concatenate([u[1:], halo_hi], axis=0)
-    zy = jnp.zeros_like(u[:, :1])
-    vm = jnp.concatenate([zy, u[:, :-1]], axis=1)
-    vp = jnp.concatenate([u[:, 1:], zy], axis=1)
-    zz = jnp.zeros_like(u[:, :, :1])
-    wm = jnp.concatenate([zz, u[:, :, :-1]], axis=2)
-    wp = jnp.concatenate([u[:, :, 1:], zz], axis=2)
-
-    out = (c[0] * u + c[1] * um + c[2] * up + c[3] * vm + c[4] * vp
-           + c[5] * wm + c[6] * wp)
-    return out.reshape(u_flat.shape)
+    shape = (local_op.nx, local_op.ny, local_op.nz)
+    u = u_flat.reshape(*shape, *u_flat.shape[1:])
+    return local_op.apply_padded(halo_pad(u, split)).reshape(u_flat.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -141,10 +227,11 @@ def halo_stencil_matvec(c: jax.Array, u_flat: jax.Array,
 # ---------------------------------------------------------------------------
 
 def build_stencil_solver(solver: Callable,
-                         op: Stencil7Operator,
+                         op: StencilOperator,
                          mesh: Mesh,
                          *,
                          shard_axes: Optional[Sequence[str]] = None,
+                         layout: Optional[Layout] = None,
                          config: SolverConfig = SolverConfig(),
                          substrate: str = "jnp",
                          precond: PrecondLike = None,
@@ -156,38 +243,39 @@ def build_stencil_solver(solver: Callable,
     constructed ONCE; the returned (jitted) callable is what a bound
     session (:meth:`repro.api.LinearSolver.on_mesh`) caches so repeat
     sharded solves stop paying per-call retracing.
+
+    ``layout`` (:func:`grid_layout`) names the mesh axes each grid axis
+    is split over; b is taken, and x returned, in those blocks.  Default:
+    x-slabs over ``shard_axes`` (every mesh axis when None).
     """
-    axes = tuple(shard_axes if shard_axes is not None else mesh.axis_names)
-    sizes = _axis_sizes(mesh, axes)
-    n_shards = int(np.prod(sizes))
-    nx, ny, nz = op.nx, op.ny, op.nz
-    if nx % n_shards:
-        raise ValueError(f"nx={nx} not divisible by {n_shards} shards")
-    local_shape = (nx // n_shards, ny, nz)
-    c = op.c
-    pc = _shard_local_precond(precond, c, local_shape)
+    if layout is None:
+        layout = grid_layout(mesh, shard_axes=shard_axes)
+    split, local_shape = decompose(mesh, layout, (op.nx, op.ny, op.nz))
+    local_op = type(op)(op.c, *local_shape)
+    pc = _shard_local_precond(precond, local_op)
+    reduce_axes = tuple(a for axes in layout for a in axes)
 
     def dot_reduce(partials):
-        return lax.psum(partials, axes)   # ONE reduction for all dots
+        return lax.psum(partials, reduce_axes)   # ONE reduction for all dots
 
     def shard_fn(b_local):
-        mv = functools.partial(halo_stencil_matvec, c,
-                               local_shape=local_shape, axes=axes, sizes=sizes)
+        mv = functools.partial(halo_matvec, local_op, split)
         with _deprecation.internal_use():
             res = solver(mv, b_local.reshape(-1), config=config,
                          dot_reduce=dot_reduce, substrate=substrate,
                          precond=pc)
         return res._replace(x=res.x.reshape(local_shape))
 
-    in_specs = P(axes)
+    _note_halo_bytes(split, local_shape, jnp.dtype(op.dtype).itemsize)
+    spec = _spec(layout)
     # the trace ring buffer is built from psum-replicated dot-derived
     # scalars, so every shard holds the same buffer: replicated specs
     out_specs = SolveResult(
-        x=P(axes), iterations=P(), relres=P(), converged=P(),
+        x=spec, iterations=P(), relres=P(), converged=P(),
         breakdown=P(), residual_history=P(), status=P(),
         trace={"buffer": P(), "steps": P()} if config.trace_cap else None)
 
-    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(in_specs,),
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec,),
                        out_specs=out_specs, check_vma=False)
     if jit:
         fn = jax.jit(fn)
@@ -195,7 +283,7 @@ def build_stencil_solver(solver: Callable,
 
 
 def distributed_stencil_solve(solver: Callable,
-                              op: Stencil7Operator,
+                              op: StencilOperator,
                               b_grid: jax.Array,
                               mesh: Mesh,
                               *,
@@ -215,7 +303,7 @@ def distributed_stencil_solve(solver: Callable,
     updates inside each shard come from that substrate, while the global
     reduction stays this driver's single ``psum`` either way.
 
-    ``precond`` is resolved against the LOCAL slab operator
+    ``precond`` is resolved against the LOCAL block operator
     (:func:`_shard_local_precond`), so every preconditioner apply is
     shard-local — zero extra communication and an unchanged single psum
     per reduction phase.
@@ -233,7 +321,7 @@ def distributed_stencil_solve(solver: Callable,
         substrate=substrate, precond=precond, jit=jit)(b_grid)
 
 
-def build_stencil_solver_batched(op: Stencil7Operator,
+def build_stencil_solver_batched(op: StencilOperator,
                                  mesh: Mesh,
                                  *,
                                  shard_axes: Optional[Sequence[str]] = None,
@@ -246,29 +334,26 @@ def build_stencil_solver_batched(op: Stencil7Operator,
     The reusable half of :func:`distributed_stencil_solve_batched` (see
     :func:`build_stencil_solver`); the returned callable accepts any
     column count m — ``jax.jit`` keys the compiled program by shape.
+    B is taken in x-slabs over ``shard_axes`` (every mesh axis when None).
     """
     from .multirhs import solve_batched
 
-    axes = tuple(shard_axes if shard_axes is not None else mesh.axis_names)
-    sizes = _axis_sizes(mesh, axes)
-    n_shards = int(np.prod(sizes))
-    nx, ny, nz = op.nx, op.ny, op.nz
-    if nx % n_shards:
-        raise ValueError(f"nx={nx} not divisible by {n_shards} shards")
-    local_shape = (nx // n_shards, ny, nz)
-    n_local = local_shape[0] * ny * nz
-    c = op.c
+    layout = grid_layout(mesh, shard_axes=shard_axes)
+    split, local_shape = decompose(mesh, layout, (op.nx, op.ny, op.nz))
+    n_local = math.prod(local_shape)
+    local_op = type(op)(op.c, *local_shape)
     # shard-local preconditioner (shape-polymorphic apply: the same bound
     # M^{-1} serves the (n_local, m) block — one build for all m columns)
-    pc = _shard_local_precond(precond, c, local_shape)
+    pc = _shard_local_precond(precond, local_op)
+    reduce_axes = layout[0]
 
     def dot_reduce(partials):
-        return lax.psum(partials, axes)   # ONE reduction: the (9, m) block
+        return lax.psum(partials, reduce_axes)   # ONE reduction: (9, m)
 
     def shard_fn(b_local):
         m = b_local.shape[-1]
-        mv = functools.partial(halo_stencil_matvec, c,
-                               local_shape=local_shape, axes=axes, sizes=sizes)
+        _note_halo_bytes(split, local_shape, b_local.dtype.itemsize, m)
+        mv = functools.partial(halo_matvec, local_op, split)
         # NOTE: no r0_star passthrough — a global shadow vector would have
         # to be row-sharded alongside B for the per-shard partial dots to
         # be correct; the default (RS = R0, already local) is what the
@@ -279,11 +364,11 @@ def build_stencil_solver_batched(op: Stencil7Operator,
                                 substrate=substrate, blocked=True, precond=pc)
         return res._replace(x=res.x.reshape(*local_shape, m))
 
-    in_specs = P(axes)
+    in_specs = _spec(layout)
     # the trace ring buffer is built from psum-replicated dot-derived
     # scalars, so every shard holds the same buffer: replicated specs
     out_specs = SolveResult(
-        x=P(axes), iterations=P(), relres=P(), converged=P(),
+        x=in_specs, iterations=P(), relres=P(), converged=P(),
         breakdown=P(), residual_history=P(), status=P(),
         trace={"buffer": P(), "steps": P()} if config.trace_cap else None)
 
@@ -301,7 +386,7 @@ def build_stencil_solver_batched(op: Stencil7Operator,
     return fn
 
 
-def distributed_stencil_solve_batched(op: Stencil7Operator,
+def distributed_stencil_solve_batched(op: StencilOperator,
                                       B_grid: jax.Array,
                                       mesh: Mesh,
                                       *,

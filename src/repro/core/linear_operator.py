@@ -12,6 +12,8 @@ All operators expose ``matvec`` (jit/vmap-safe pure function of a vector),
   operator with optional convection (non-symmetric) terms; the structured
   analogue of the paper's fluid-dynamics matrices, and the operator used by
   the distributed halo-exchange path.
+* :class:`Stencil27Operator` — matrix-free 27-point (3-D) stencil, HPCG's
+  operator among them; sharded by the same halo-exchange path.
 
 Design note: operators are pytrees (registered dataclasses) so they can be
 closed over or passed as arguments to jitted solvers and sharded with
@@ -227,8 +229,83 @@ class Stencil7Operator:
             + c[5] * wm + c[6] * wp
         return out.reshape(-1)
 
+    def apply_padded(self, p: jax.Array) -> jax.Array:
+        """A u on a block ``u`` given as ``p``, the block with one plane
+        more on each side of each grid axis (its neighbours' planes, or
+        zeros at the boundary); trailing column axes ride along.  The
+        terms are summed in :meth:`matvec`'s order."""
+        c = self.c
+        u = p[1:-1, 1:-1, 1:-1]
+        return (c[0] * u + c[1] * p[:-2, 1:-1, 1:-1] + c[2] * p[2:, 1:-1, 1:-1]
+                + c[3] * p[1:-1, :-2, 1:-1] + c[4] * p[1:-1, 2:, 1:-1]
+                + c[5] * p[1:-1, 1:-1, :-2] + c[6] * p[1:-1, 1:-1, 2:])
+
     def diagonal(self) -> jax.Array:
         return jnp.full((self.n,), self.c[0], dtype=self.dtype)
+
+    def tree_flatten(self):
+        return (self.c,), (self.nx, self.ny, self.nz)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(children[0], *aux)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass(frozen=True)
+class Stencil27Operator:
+    """Matrix-free 27-point stencil on an (nx, ny, nz) grid, zero
+    Dirichlet boundaries: a row keeps only its in-domain neighbours.
+
+    ``c`` is ``(3, 3, 3)``: ``c[dx + 1, dy + 1, dz + 1]`` couples point
+    (i, j, k) to (i + dx, j + dy, k + dz).  :meth:`hpcg` gives HPCG's
+    operator (diagonal 26, every neighbour -1), which is SPD.  The
+    distributed driver shards it like :class:`Stencil7Operator`, with
+    halos over every split grid axis.
+    """
+
+    c: jax.Array  # (3, 3, 3)
+    nx: int
+    ny: int
+    nz: int
+
+    @classmethod
+    def hpcg(cls, nx: int, ny: int, nz: int,
+             dtype=jnp.float32) -> "Stencil27Operator":
+        """HPCG 3.1's operator (``GenerateProblem_ref.cpp``)."""
+        c = np.full((3, 3, 3), -1.0)
+        c[1, 1, 1] = 26.0
+        return cls(jnp.asarray(c, dtype), nx, ny, nz)
+
+    @property
+    def n(self):
+        return self.nx * self.ny * self.nz
+
+    @property
+    def shape(self):
+        return (self.n, self.n)
+
+    @property
+    def dtype(self):
+        return self.c.dtype
+
+    def matvec(self, x: jax.Array) -> jax.Array:
+        u = x.reshape(self.nx, self.ny, self.nz)
+        return self.apply_padded(jnp.pad(u, 1)).reshape(-1)
+
+    def apply_padded(self, p: jax.Array) -> jax.Array:
+        """A u on a block ``u`` given as ``p``, the block with one plane
+        more on each side of each grid axis; trailing column axes ride
+        along."""
+        nx, ny, nz = (s - 2 for s in p.shape[:3])
+        out = None
+        for i, j, k in np.ndindex(3, 3, 3):
+            term = self.c[i, j, k] * p[i:i + nx, j:j + ny, k:k + nz]
+            out = term if out is None else out + term
+        return out
+
+    def diagonal(self) -> jax.Array:
+        return jnp.full((self.n,), self.c[1, 1, 1], dtype=self.dtype)
 
     def tree_flatten(self):
         return (self.c,), (self.nx, self.ny, self.nz)

@@ -336,6 +336,13 @@ REQUEST_CHUNKS = REGISTRY.histogram(
     "engine chunks a request stayed resident",
     buckets=(1., 2., 3., 5., 8., 13., 21., 34., 55., 89.))
 
+MESH_HALO_BYTES = REGISTRY.gauge(
+    "repro_mesh_halo_bytes",
+    "bytes one device sends per matvec of the sharded program last "
+    "built, along each split grid axis: a plane to each neighbour, "
+    "counted from the block shapes",
+    labels=("axis",))
+
 RECOVERY_ACTIONS = REGISTRY.counter(
     "repro_recovery_actions_total",
     "guarded-solve recovery actions fired, by action",

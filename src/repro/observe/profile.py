@@ -14,6 +14,9 @@ the fraction of reduction/collective device wall time hidden under the
 in-flight matvec (interval-union intersection, so concurrent ops are not
 double counted), plus the complementary *exposed* communication time per
 iteration — exactly how Cools & Vanroose evaluate pipelined solvers.
+On a mesh the matvec's halo exchange (scope ``repro.halo`` inside
+``repro.matvec``, or a collective-permute) is also reported on its own,
+as ``halo_us``: part of the matvec phase, not a phase of its own.
 
 Phase attribution works in two layers:
 
@@ -70,6 +73,11 @@ _NAME_RULES: Tuple[Tuple[str, str], ...] = (
     ("fused_axpy", "axpy"), ("axpy_phase", "axpy"),
     ("precond", "precond"),
 )
+
+#: a matvec op that is the halo exchange: its scope, or its name where
+#: the op has no mapped scope
+_HALO_SCOPE = "repro.halo"
+_HALO_NAMES = ("collective-permute", "ppermute")
 
 
 # ---------------------------------------------------------------------------
@@ -190,6 +198,12 @@ def hlo_op_map(compiled_text: str) -> Tuple[str, Dict[str, str]]:
     return module, ops
 
 
+def _is_halo(name: str, scope: str = "") -> bool:
+    """Whether one device op is the matvec's halo exchange."""
+    hay = f"{scope}/{name}".lower()
+    return _HALO_SCOPE in hay or any(p in hay for p in _HALO_NAMES)
+
+
 def classify_op(name: str, scope: str = "") -> str:
     """Phase of one device op: scope tags first, then name patterns."""
     hay = f"{scope}/{name}".lower()
@@ -267,6 +281,8 @@ class ProfileReport:
     host_spans: Dict[str, Dict[str, float]]
     label: str = ""
     timeline_path: Optional[str] = None
+    #: device time of the halo exchange, counted inside ``matvec_us``
+    halo_us: float = 0.0
 
     def to_json(self) -> Dict[str, Any]:
         d = dataclasses.asdict(self)
@@ -301,6 +317,9 @@ class ProfileReport:
             lines.append(f"  {ph:<8} {us / 1e3:9.3f} ms "
                          f"|{bar:<{width}}| {100 * frac:5.1f}%  "
                          f"({self.phase_ops.get(ph, 0)} ops)")
+            if ph == "matvec" and self.halo_us:
+                lines.append(f"    halo {self.halo_us / 1e3:9.3f} ms of it "
+                             "(the exchange between devices)")
         lines.append(f"  device wall {self.device_wall_us / 1e3:.3f} ms, "
                      f"{self.n_device_events} device events"
                      + (f", {self.unmapped_ops} unmapped"
@@ -339,6 +358,7 @@ def analyze_timeline(src: Any,
     events = device_events(doc)
 
     phase_iv: Dict[str, List[Tuple[float, float]]] = {p: [] for p in PHASES}
+    halo_iv: List[Tuple[float, float]] = []
     phase_ops: Dict[str, set] = {p: set() for p in PHASES}
     op_counts: Dict[Tuple[str, str, str], int] = {}
     unmapped = 0
@@ -361,6 +381,8 @@ def analyze_timeline(src: Any,
         ts = float(e.get("ts", 0.0))
         dur = float(e.get("dur", 0.0))
         phase_iv[phase].append((ts, ts + dur))
+        if phase == "matvec" and _is_halo(op, scope):
+            halo_iv.append((ts, ts + dur))
         phase_ops[phase].add((module, op))
         key = (module, op, phase)
         op_counts[key] = op_counts.get(key, 0) + 1
@@ -398,6 +420,7 @@ def analyze_timeline(src: Any,
         host_spans=host_spans(doc),
         label=label,
         timeline_path=src if isinstance(src, str) else None,
+        halo_us=total(merge_intervals(halo_iv)),
     )
 
 

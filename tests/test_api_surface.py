@@ -25,6 +25,7 @@ PUBLIC_API = [
     "SolveResult",
     "SolveStatus",
     "SolverConfig",
+    "Stencil27Operator",
     "Stencil7Operator",
     "get_substrate",
     "make_solver",

@@ -193,6 +193,27 @@ def test_analyze_with_hlo_map_and_spmd_prefix_fallback():
     assert rep.unmapped_ops == 0
 
 
+def test_halo_time_is_reported_inside_matvec():
+    """The exchange (scope repro.halo inside repro.matvec, or a bare
+    collective-permute) counts in matvec and is reported as halo_us."""
+    maps = {"m": {"fusion.7": "jit(p)/while/body/repro.matvec/repro.halo/"
+                              "ppermute"}}
+    doc = {"traceEvents": [
+        {"ph": "X", "pid": 1, "tid": 1, "ts": 0.0, "dur": 10.0,
+         "args": {"hlo_op": "fusion.7", "hlo_module": "m"}},
+        {"ph": "X", "pid": 1, "tid": 1, "ts": 5.0, "dur": 10.0,
+         "args": {"hlo_op": "collective-permute-done.3", "hlo_module": "m"}},
+        {"ph": "X", "pid": 1, "tid": 1, "ts": 20.0, "dur": 30.0,
+         "args": {"hlo_op": "stencil_fusion.1", "hlo_module": "m"}},
+    ]}
+    rep = P.analyze_timeline(doc, hlo_maps=maps)
+    assert rep.halo_us == pytest.approx(15.0)
+    assert rep.matvec_us == pytest.approx(45.0)
+    assert set(rep.phase_us) == set(P.PHASES)
+    assert "halo" in rep.render()
+    assert P.ProfileReport.from_json(rep.to_json()).halo_us == rep.halo_us
+
+
 # ---------------------------------------------------------------------------
 # report round-trip
 # ---------------------------------------------------------------------------

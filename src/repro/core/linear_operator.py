@@ -262,6 +262,14 @@ class Stencil27Operator:
     operator (diagonal 26, every neighbour -1), which is SPD.  The
     distributed driver shards it like :class:`Stencil7Operator`, with
     halos over every split grid axis.
+
+    :meth:`apply_padded` takes one of two forms, chosen from ``c`` as
+    the program is traced (:meth:`form`): where all 26 off-centre coefficients are
+    one number ``a`` (HPCG's), the ``"box"`` form ``(c_centre - a) u +
+    a B(u)``, with B the 3x3x3 box sum taken as three 3-point sums along
+    z, y and x; otherwise, or where ``c`` is itself traced, the
+    ``"general"`` form, the 27 products summed in turn.  Both give the
+    same operator; only the order in which the sums round differs.
     """
 
     c: jax.Array  # (3, 3, 3)
@@ -289,6 +297,14 @@ class Stencil27Operator:
     def dtype(self):
         return self.c.dtype
 
+    def form(self) -> str:
+        """``"box"`` where every off-centre coefficient is the same
+        number, else ``"general"``, as it is where ``c`` is a tracer."""
+        if isinstance(self.c, jax.core.Tracer):
+            return "general"
+        off = np.delete(np.asarray(self.c).reshape(-1), 13)
+        return "box" if (off == off[0]).all() else "general"
+
     def matvec(self, x: jax.Array) -> jax.Array:
         u = x.reshape(self.nx, self.ny, self.nz)
         return self.apply_padded(jnp.pad(u, 1)).reshape(-1)
@@ -296,7 +312,20 @@ class Stencil27Operator:
     def apply_padded(self, p: jax.Array) -> jax.Array:
         """A u on a block ``u`` given as ``p``, the block with one plane
         more on each side of each grid axis; trailing column axes ride
-        along."""
+        along.  Counts the call in ``repro_stencil_form_total``."""
+        from repro.observe.metrics import STENCIL_FORM
+        form = self.form()
+        STENCIL_FORM.inc(operator="stencil27", form=form)
+        if form == "box":
+            c = np.asarray(self.c)
+            a = c[0, 0, 0]
+            box = p
+            for axis in (2, 1, 0):
+                n = box.shape[axis] - 2
+                lo, mid, hi = (lax.slice_in_dim(box, k, k + n, axis=axis)
+                               for k in range(3))
+                box = lo + mid + hi
+            return (c[1, 1, 1] - a) * p[1:-1, 1:-1, 1:-1] + a * box
         nx, ny, nz = (s - 2 for s in p.shape[:3])
         out = None
         for i, j, k in np.ndindex(3, 3, 3):

@@ -342,6 +342,12 @@ MESH_HALO_BYTES = REGISTRY.gauge(
     "built, along each split grid axis: a plane to each neighbour, "
     "counted from the block shapes",
     labels=("axis",))
+STENCIL_FORM = REGISTRY.counter(
+    "repro_stencil_form_total",
+    "stencil applies traced, by operator and arithmetic form (box: the "
+    "separable box sum of equal off-centre coefficients; general: one "
+    "product a coefficient), counted when a program is traced",
+    labels=("operator", "form"))
 
 RECOVERY_ACTIONS = REGISTRY.counter(
     "repro_recovery_actions_total",

@@ -2,7 +2,8 @@
 
 Invoked by tests/test_stencil27.py.  On process grids 2x2x1, 4x1x1 and
 1x2x2, with the right-hand side laid out by ``P("x", "y", "z")``: the
-halo matvec equals the one-device matvec, the sharded solve meets its
+halo matvec, in the box form HPCG's coefficients take, equals the
+one-device matvec in either form, the sharded solve meets its
 tol by its true residual in about the one-device iterations and returns
 x in b's blocks, the loop body holds one psum with no dependency path to
 the halo permutes, the halo-bytes gauge is the shapes' count, and the
@@ -51,8 +52,18 @@ def check_grid(shape, op, b, ref_iters):
     u = jax.device_put(jnp.asarray(np.random.default_rng(5).standard_normal(
         GRID)), sharding)
     want = np.asarray(op.matvec(jnp.asarray(u).reshape(-1))).reshape(GRID)
+    boxes = metrics.STENCIL_FORM.value(operator="stencil27", form="box")
     got = np.asarray(mv(u.reshape(GRID)))
     err = np.abs(got - want).max() / np.abs(want).max()
+    assert err < 1e-6, (shape, err)
+
+    # ... in the box form, against the one-device 27-term form (the
+    # operator passed into jit as an argument traces its c)
+    assert metrics.STENCIL_FORM.value(
+        operator="stencil27", form="box") == boxes + 1, shape
+    general = np.asarray(jax.jit(lambda o, v: o.matvec(v))(
+        op, jnp.asarray(u).reshape(-1))).reshape(GRID)
+    err = np.abs(got - general).max() / np.abs(general).max()
     assert err < 1e-6, (shape, err)
 
     # the sharded solve: true residual, iterations, x in b's blocks

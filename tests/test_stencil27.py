@@ -1,19 +1,23 @@
 """Stencil27Operator: HPCG's 27-point operator against a matrix
-assembled entry by entry, its one-device solve, and (in a subprocess
-with four host devices, tests/_stencil27_mesh_check.py) its sharded
-solve on 2x2x1, 4x1x1 and 1x2x2 process grids."""
+assembled entry by entry, its two arithmetic forms, its one-device
+solve, and (in a subprocess with four host devices,
+tests/_stencil27_mesh_check.py) its sharded solve on 2x2x1, 4x1x1 and
+1x2x2 process grids."""
 import itertools
 import os
 import subprocess
 import sys
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Var
 
 import repro
 from repro import SolverConfig, Stencil27Operator
+from repro.observe.metrics import STENCIL_FORM
 
 HERE = os.path.dirname(__file__)
 SRC = os.path.abspath(os.path.join(HERE, os.pardir, "src"))
@@ -47,6 +51,84 @@ def test_matvec_is_the_assembled_matrix(make):
     got = np.asarray(op.matvec(jnp.asarray(x)))
     np.testing.assert_allclose(got, a @ x, rtol=1e-5, atol=1e-5)
     assert op.shape == a.shape and op.dtype == jnp.float32
+
+
+def equal_op(nx, ny, nz):
+    """Centre 3.0, every neighbour -0.1: a second equal-off-centre set."""
+    c = np.full((3, 3, 3), -0.1)
+    c[1, 1, 1] = 3.0
+    return Stencil27Operator(jnp.asarray(c, jnp.float32), nx, ny, nz)
+
+
+def padded_block(op, columns, seed=6):
+    """A padded block whose halo planes are non-zero, as a neighbour's
+    are on a mesh."""
+    shape = (op.nx + 2, op.ny + 2, op.nz + 2) + columns
+    return jnp.asarray(np.random.default_rng(seed).standard_normal(shape),
+                       jnp.float32)
+
+
+def traced_apply(op, p):
+    """``apply_padded`` with the operator passed into ``jax.jit`` as an
+    argument: ``c`` is a tracer, so the general form."""
+    return jax.jit(lambda o, v: o.apply_padded(v))(op, p)
+
+
+def form_counts():
+    return {f: STENCIL_FORM.value(operator="stencil27", form=f)
+            for f in ("box", "general")}
+
+
+@pytest.mark.parametrize("columns", [(), (3,)], ids=["vector", "columns3"])
+@pytest.mark.parametrize("make", [Stencil27Operator.hpcg, equal_op],
+                         ids=["hpcg", "equal"])
+def test_box_form_is_the_general_form(make, columns):
+    """Equal off-centre coefficients take the box form, which gives the
+    27-term sum to float32 rounding, trailing columns included."""
+    op = make(9, 8, 7)
+    p = padded_block(op, columns)
+    assert op.form() == "box"
+    box = np.asarray(op.apply_padded(p))
+    general = np.asarray(traced_apply(op, p))
+    assert box.shape == general.shape == (9, 8, 7) + columns
+    np.testing.assert_allclose(box, general, rtol=1e-5,
+                               atol=1e-5 * np.abs(general).max())
+
+
+def p_slices(closed):
+    """The ``slice`` equations whose operand is the padded block or was
+    computed from it (the last input), not from the coefficients."""
+    jaxpr = closed.jaxpr
+    derived, n = {jaxpr.invars[-1]}, 0
+    for eqn in jaxpr.eqns:
+        if any(v in derived for v in eqn.invars if isinstance(v, Var)):
+            derived.update(eqn.outvars)
+            n += eqn.primitive.name == "slice"
+    return n
+
+
+@pytest.mark.parametrize("case,form", [
+    ("hpcg", "box"), ("equal", "box"), ("random", "general"),
+    ("traced", "general")])
+def test_apply_takes_the_form_its_coefficients_allow(case, form):
+    """One traced apply counts once in ``repro_stencil_form_total``, in
+    the form its coefficients allow: the box form slices the padded
+    block at most 10 times (3 a sum, 1 for u), the general form 27."""
+    make = {"hpcg": Stencil27Operator.hpcg, "equal": equal_op,
+            "random": random_op, "traced": Stencil27Operator.hpcg}[case]
+    op = make(9, 8, 7)
+    p = padded_block(op, ())
+    before = form_counts()
+    if case == "traced":
+        closed = jax.make_jaxpr(lambda o, v: o.apply_padded(v))(op, p)
+    else:
+        assert op.form() == form
+        closed = jax.make_jaxpr(op.apply_padded)(p)
+    after = form_counts()
+    assert {f: after[f] - before[f] for f in after} == {
+        f: float(f == form) for f in after}
+    n = p_slices(closed)
+    assert n <= 10 if form == "box" else n == 27, (case, n)
 
 
 def test_diagonal():

@@ -1,5 +1,6 @@
-"""Compile the main path's Pallas kernels and the pallas p-BiCGSafe solve
-program for a described (not attached) TPU v5e, at 256^3 in float32.
+"""Compile the main path's Pallas kernels, the pallas p-BiCGSafe solve
+program and the 27-point stencil's two forms for a described (not
+attached) TPU v5e, at 256^3 in float32.
 
 Interpret mode, which every other test runs the kernels in, does not
 check what Mosaic refuses: block shapes off the (8, 128) tiling, in-kernel
@@ -8,6 +9,7 @@ described inside a module fixture, never at import: only the worker that
 runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +110,30 @@ def test_pallas_solve_program_compiles_for_v5e(one_chip, monkeypatch):
     b = jax.ShapeDtypeStruct((N,), jnp.float32, sharding=one_chip)
     compiled = jax.jit(lambda b: solver.solve(b).x).lower(b).compile()
     assert compiled.as_text().count(CUSTOM_CALL) >= 2   # dots + axpy
+
+
+def _estimated_cycles(fn, *args):
+    """The compiler's estimated cycles, summed over the program's ops."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return sum(int(c) for c in re.findall(r'"estimated_cycles":"(\d+)"',
+                                          text))
+
+
+def test_stencil27_box_form_is_cheaper_for_v5e(one_chip):
+    """HPCG's 27-point apply on a padded 256^3 block: the box form (three
+    3-point sums) is estimated at most 0.6x the cycles of the 27-term
+    form, which the same coefficients take when passed as an argument."""
+    from repro.core.linear_operator import Stencil27Operator
+
+    p = jax.ShapeDtypeStruct((258, 258, 258), jnp.float32,
+                             sharding=one_chip)
+    op = Stencil27Operator.hpcg(256, 256, 256)
+    traced = Stencil27Operator(
+        jax.ShapeDtypeStruct((3, 3, 3), jnp.float32, sharding=one_chip),
+        256, 256, 256)
+    box = _estimated_cycles(op.apply_padded, p)
+    general = _estimated_cycles(lambda o, v: o.apply_padded(v), traced, p)
+    assert 0 < box <= 0.6 * general, (box, general)
 
 
 def test_pallas_refuses_float64_at_bind_on_tpu(monkeypatch):
